@@ -66,6 +66,9 @@ def main() -> None:
             # a silent no-op harness would look green in CI
             ap.error(f"unknown --only keys: {','.join(sorted(unknown))}")
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     out_file = open(args.out, "w") if args.out else None
     prev_stdout = sys.stdout
     if out_file is not None:

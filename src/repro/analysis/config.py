@@ -46,8 +46,7 @@ class KernelContractConfig:
     # a passing test elsewhere does not keep kernel/oracle drift attributable.
     test_files: tuple[str, ...] = ("tests/test_kernels.py",)
     # Infrastructure modules in kernels_dir that are not kernels themselves.
-    non_kernel_modules: tuple[str, ...] = ("__init__.py", "ops.py", "ref.py",
-                                           "_compat.py")
+    non_kernel_modules: tuple[str, ...] = ("__init__.py", "ops.py", "ref.py")
 
 
 @dataclasses.dataclass(frozen=True)

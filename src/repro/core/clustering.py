@@ -246,6 +246,13 @@ def _jax_sweeps():
     import jax
     import jax.numpy as jnp
 
+    # Every product here runs at HIGHEST precision: at the default, the
+    # TPU's MXU rounds f32 operands to bf16, which moves the distances and
+    # the cluster sums enough to leave the fit off float64 Lloyd's fixed
+    # point (0.9936 label agreement at 1e6 rows on a TPU v5e, against
+    # 0.9998 on the CPU).
+    hi = jax.lax.Precision.HIGHEST
+
     def _assign(xc, Cf, K, M):
         """(CH, d) points vs (K*M, d) stacked centroids -> (CH, K) labels.
 
@@ -255,7 +262,8 @@ def _jax_sweeps():
         """
         x2 = (xc * xc).sum(-1)[:, None]
         c2 = (Cf * Cf).sum(-1)[None, :]
-        d2 = (x2 - 2.0 * (xc @ Cf.T) + c2).reshape(-1, K, M)
+        xcf = jnp.dot(xc, Cf.T, precision=hi)
+        d2 = (x2 - 2.0 * xcf + c2).reshape(-1, K, M)
         return jnp.argmin(d2, axis=-1)
 
     @jax.jit
@@ -278,7 +286,7 @@ def _jax_sweeps():
             oh = (lab[..., None] == jnp.arange(M)[None, None, :]
                   ).astype(jnp.float32)                       # (B, K, M)
             cnt = oh.sum(0)                                   # (K, M)
-            sums = jnp.einsum("bkm,bd->kmd", oh, xb)
+            sums = jnp.einsum("bkm,bd->kmd", oh, xb, precision=hi)
             counts = counts + cnt
             lr = jnp.where(cnt > 0, cnt / jnp.maximum(counts, 1.0), 0.0)
             tgt = sums / jnp.maximum(cnt, 1.0)[..., None]
@@ -313,7 +321,8 @@ def _jax_sweeps():
                 lab = _assign(xc, Cf, K, M)                   # (CH, K)
                 oh = (lab[..., None] == jnp.arange(M)[None, None, :]
                       ).astype(jnp.float32) * wv[:, None, None]
-                sums = sums + jnp.einsum("bkm,bd->kmd", oh, xc)
+                sums = sums + jnp.einsum("bkm,bd->kmd", oh, xc,
+                                         precision=hi)
                 cnt = cnt + oh.sum(0)
                 ys = lab.astype(jnp.int32) if want_labels else None
                 return (sums, cnt), ys

@@ -8,7 +8,6 @@ parallelism.
                        lets ``TransferTuner`` optimize bucketing parameters.
   * ``pipeline_par`` — GPipe-style pipeline parallelism over a ``stage``
                        mesh axis via collective-permute.
-  * ``compat``       — jax version shims (``shard_map`` spelling).
 
 Submodules are imported explicitly by callers (never here) so that entry
 points like ``launch/dryrun.py`` can set XLA flags before jax initializes.

@@ -12,10 +12,8 @@ import dataclasses
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
-
-from repro.dist.compat import shard_map
 
 
 @dataclasses.dataclass(frozen=True)

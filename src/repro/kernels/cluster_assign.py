@@ -25,7 +25,11 @@ def _assign_kernel(x_ref, c_ref, lab_ref, dist_ref):
     c = c_ref[...].astype(jnp.float32)  # (M, d)
     x2 = (x * x).sum(axis=1, keepdims=True)  # (NB, 1)
     c2 = (c * c).sum(axis=1)[None, :]  # (1, M)
-    xc = jax.lax.dot_general(x, c, (((1,), (1,)), ((), ())))  # (NB, M) on MXU
+    # HIGHEST: at the default the MXU rounds f32 operands to bf16, which
+    # moves labels off float64 Lloyd's (see core.clustering)
+    xc = jax.lax.dot_general(
+        x, c, (((1,), (1,)), ((), ())), precision=jax.lax.Precision.HIGHEST
+    )  # (NB, M) on MXU
     d2 = jnp.maximum(x2 - 2.0 * xc + c2, 0.0)
     lab_ref[...] = jnp.argmin(d2, axis=1).astype(jnp.int32)[:, None]
     dist_ref[...] = jnp.min(d2, axis=1)[:, None]

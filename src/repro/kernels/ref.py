@@ -409,7 +409,8 @@ def cluster_assign_ref(X, C):
     C = jnp.asarray(C, jnp.float32)
     x2 = (X * X).sum(-1, keepdims=True)                  # (N, 1)
     c2 = (C * C).sum(-1)[None, :]                        # (1, M)
-    d2 = jnp.maximum(x2 - 2.0 * (X @ C.T) + c2, 0.0)     # (N, M)
+    xc = jnp.dot(X, C.T, precision=jax.lax.Precision.HIGHEST)
+    d2 = jnp.maximum(x2 - 2.0 * xc + c2, 0.0)            # (N, M)
     return jnp.argmin(d2, axis=1).astype(jnp.int32), jnp.min(d2, axis=1)
 
 

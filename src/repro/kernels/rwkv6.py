@@ -18,8 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
-
 
 def _rwkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, state_scr, *,
                  chunk: int):
@@ -90,7 +88,7 @@ def rwkv6_pallas(r, k, v, w, u, *, chunk: int = 16, initial_state=None,
         out_shape=jax.ShapeDtypeStruct((B, L, H, V), r.dtype),
         scratch_shapes=[pltpu.VMEM((K, V), jnp.float32)],
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
     )(r, k, v, w, u)
     if return_state:

@@ -20,8 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
-
 
 def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, o_ref, state_scr, *,
                 chunk: int, has_init: bool):
@@ -97,7 +95,7 @@ def ssd_pallas(x, dt, A, B, C, *, chunk: int = 128, initial_state=None,
         out_shape=jax.ShapeDtypeStruct((Bsz, L, H, P), x.dtype),
         scratch_shapes=[pltpu.VMEM((H, P, N), jnp.float32)],
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
     )(x, dt, A, B, C)
     if return_state:

@@ -2,12 +2,17 @@
 
 Scoring B requests x S surfaces x P candidate points is a gather from the
 stacked integer-lattice surface tensors (see ``core.batched``).  TPUs dislike
-scatters and gathers but love matmuls, so the kernel expands each request
-block's candidate indices into a one-hot ``(BB * P, G)`` tile and contracts it
-with the ``(S, G)`` value stack on the MXU, then reduces the ``(BB, P, S)``
-scores to the best candidate per (request, surface) pair in VMEM.  The XLA
-oracle lives in ``kernels.ref.batched_predict_argmax_ref`` and is the default
-compute path off-TPU.
+scatters and gathers but love matmuls, so the kernel walks the P candidate
+columns of each request block: column p becomes a one-hot ``(BB, G)`` tile,
+one MXU contraction with the ``(S, G)`` value stack scores it for every
+surface, and a running max/argmax over the columns keeps the best candidate
+per (request, surface) pair in VMEM.  Candidate indices stay in lanes and
+are only ever broadcast along them, never reshaped into sublanes, which
+Mosaic cannot lay out.  The contraction runs at ``Precision.HIGHEST``: a
+one-hot row times the stack then sums one f32 value with exact zeros, so the
+kernel returns surface values bit-exactly.  The XLA oracle lives in
+``kernels.ref.batched_predict_argmax_ref`` and is the default compute path
+off-TPU.
 """
 
 from __future__ import annotations
@@ -20,23 +25,37 @@ from jax.experimental import pallas as pl
 
 
 def _select_kernel(idx_ref, val_ref, best_ref, argk_ref, *, bb, n_cand, n_grid):
-    idx = idx_ref[...].reshape(bb * n_cand, 1)
-    cols = jax.lax.broadcasted_iota(jnp.int32, (bb * n_cand, n_grid), 1)
-    onehot = (cols == idx).astype(jnp.float32)
     vals = val_ref[...].astype(jnp.float32)  # (S, G)
-    scores = jax.lax.dot_general(onehot, vals, (((1,), (1,)), ((), ())))
-    scores = scores.reshape(bb, n_cand, vals.shape[0])  # (BB, P, S)
-    best_ref[...] = jnp.max(scores, axis=1)
-    argk_ref[...] = jnp.argmax(scores, axis=1).astype(jnp.int32)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (bb, n_grid), 1)
+
+    def score(p):  # candidate column p on every surface -> (BB, S)
+        onehot = (cols == idx_ref[:, p : p + 1]).astype(jnp.float32)  # (BB, G)
+        return jax.lax.dot_general(
+            onehot,
+            vals,
+            (((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32,
+        )
+
+    best = score(0)
+    argk = jnp.zeros(best.shape, jnp.int32)
+    for p in range(1, n_cand):  # static unroll; P is a handful of candidates
+        s = score(p)
+        better = s > best  # strict: the first maximal candidate wins, as in argmax
+        best = jnp.where(better, s, best)
+        argk = jnp.where(better, p, argk)
+    best_ref[...] = best
+    argk_ref[...] = argk
 
 
 @functools.partial(jax.jit, static_argnames=("bb", "interpret"))
 def batched_predict_argmax_pallas(values, idx, *, bb: int = 8, interpret: bool = False):
     """values (S, G) f32, idx (B, P) int32 -> (best (B, S), argk (B, S)).
 
-    One grid step per ``bb``-request block; the one-hot tile keeps the VMEM
-    working set at ``bb * P * G * 4`` bytes (~2 MB at bb=8, P=16, G=4096), and
-    the whole value stack rides along in VMEM since S is small.
+    One grid step per ``bb``-request block; one candidate column's one-hot
+    tile is ``bb * G * 4`` bytes (128 KB at bb=8, G=4096), and the whole
+    value stack rides along in VMEM since S is small.
     """
     values = jnp.asarray(values, jnp.float32)
     idx = jnp.asarray(idx, jnp.int32)

@@ -220,8 +220,6 @@ def _lower_and_analyze(cfg, arch, shape, *, multi_pod: bool,
 
         mem = compiled.memory_analysis()
         cost = compiled.cost_analysis()
-        if isinstance(cost, (list, tuple)):     # jax 0.4.x: list of dicts
-            cost = cost[0] if cost else {}
         cost = cost or {}                       # backends without cost model
         hlo = compiled.as_text()
         coll = collective_bytes(hlo)

@@ -104,7 +104,7 @@ def test_flatten_roundtrip():
 
 def test_bucketed_allreduce_single_device():
     # axis of size 1: psum is identity; checks bucketing/padding plumbing
-    from repro.dist.compat import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh
     mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
     x = jnp.arange(37, dtype=jnp.float32)
@@ -116,7 +116,7 @@ def test_bucketed_allreduce_single_device():
 
 
 def test_quantized_allreduce_accuracy():
-    from repro.dist.compat import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh
     mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
     x = jnp.asarray(np.random.default_rng(0).normal(size=257), jnp.float32)

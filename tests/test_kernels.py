@@ -246,6 +246,22 @@ def test_transfer_select_pallas_matches_ref(case):
     np.testing.assert_array_equal(np.asarray(argk_p), np.asarray(argk_r))
 
 
+def test_transfer_select_pallas_exact_at_block_8():
+    """The kernel is a gather: values come back bit-exactly, and repeated
+    candidates and tied values pick the first maximal column like argmax."""
+    from repro.kernels.transfer_select import batched_predict_argmax_pallas
+
+    S, G, B, P = 6, 512, 37, 16
+    values = (RNG.integers(-8, 8, size=(S, G)) * 0.37).astype(np.float32)
+    idx = RNG.integers(0, G, size=(B, P)).astype(np.int32)
+    idx[:, 5] = idx[:, 2]  # a repeated candidate ties with an earlier one
+    best_r, argk_r = ref.batched_predict_argmax_ref(values, idx)
+    best_p, argk_p = batched_predict_argmax_pallas(values, idx, bb=8,
+                                                   interpret=True)
+    np.testing.assert_array_equal(np.asarray(best_p), np.asarray(best_r))
+    np.testing.assert_array_equal(np.asarray(argk_p), np.asarray(argk_r))
+
+
 def test_transfer_select_ops_dispatch():
     from repro.kernels.ops import transfer_predict_argmax
 
