@@ -19,6 +19,7 @@ from __future__ import annotations
 import dataclasses
 import warnings
 
+from repro import obs
 from repro.core.engine.sharded import ShardedFleetEngine
 from repro.core.engine.vectorized import VectorizedFleetEngine
 from repro.core.fleet import (
@@ -200,6 +201,9 @@ def run_fleet(
     ``config.engine`` (default ``EngineConfig()``, i.e. threaded).  A legacy
     ``FleetConfig`` is accepted for migration and converted in place with a
     ``DeprecationWarning``.
+
+    The call is the trace span ``repro.fleet.run`` (``repro.obs``); under a
+    profiler session the event-loop engines attach their counters to it.
     """
     if config is None:
         config = EngineConfig()
@@ -216,16 +220,24 @@ def run_fleet(
             "config must be EngineConfig, FleetConfig, or None, "
             f"got {type(config).__name__}"
         )
-    if config.engine == "sharded":
-        return ShardedFleetEngine(db, config).run(requests)
-    if config.engine == "vectorized":
-        return VectorizedFleetEngine(db, config).run(requests)
-    return FleetScheduler(
-        db,
-        z=config.z,
-        max_samples=config.max_samples,
-        bulk_chunks=config.bulk_chunks,
-        config=config.to_fleet_config(),
-        use_pallas=config.use_pallas,
-        knowledge=config.knowledge,
-    ).run(requests)
+    with obs.fleet(requests=len(requests), engine=config.engine) as span:
+        if config.engine == "sharded":
+            engine = ShardedFleetEngine(db, config)
+        elif config.engine == "vectorized":
+            engine = VectorizedFleetEngine(db, config)
+        else:
+            engine = FleetScheduler(
+                db,
+                z=config.z,
+                max_samples=config.max_samples,
+                bulk_chunks=config.bulk_chunks,
+                config=config.to_fleet_config(),
+                use_pallas=config.use_pallas,
+                knowledge=config.knowledge,
+            )
+        report = engine.run(requests)
+        # the event loop's counters, kept while a profiler session records
+        counters = getattr(engine, "counters", None)
+        if counters:
+            span.set_metadata(**counters)
+    return report

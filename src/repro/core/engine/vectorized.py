@@ -42,6 +42,7 @@ import heapq
 
 import numpy as np
 
+from repro import obs
 from repro.core.fleet import (
     FleetReport,
     FleetRequest,
@@ -174,6 +175,9 @@ class VectorizedFleetEngine:
         self.config = config
         self.events_processed = 0
         self.state: FleetStateArrays | None = None
+        # the last run's counters, kept only while a profiler session
+        # records (``repro.obs``): events, admissions and busy nanoseconds
+        self.counters: dict[str, int] | None = None
 
     # ------------------------------------------------------------------ #
     def _make_heap(self, n: int) -> VectorEventHeap:
@@ -221,6 +225,11 @@ class VectorizedFleetEngine:
         n = len(requests)
         if n == 0:
             return FleetReport([], 0.0, 0.0, 0.0, 0.0, 0.0, 0, 0, 0)
+        # Busy-time counters only while a profiler session records; with
+        # tracing off the loop calls the plain callables and reads no clock.
+        busy = obs.Busy() if obs.active() else None
+        step = next if busy is None else busy.timed("step", next)
+        events0 = self.events_processed
         link = TESTBEDS[cfg.testbed]
         shared = self._make_shared(link, n)
         counter = _ActiveCounter()
@@ -270,9 +279,9 @@ class VectorizedFleetEngine:
         n_kills = 0
         n_recoveries = 0
 
-        def admit_next(now_s: float) -> None:
-            if not pending:
-                return
+        def build(now_s: float) -> int:
+            """Admit the next queued request into its slot, up to the
+            session's first step."""
             i = pending.popleft()
             admit_time[i] = max(reqs[i].start_clock_s, now_s)
             state.admit_s[i] = admit_time[i]
@@ -288,6 +297,8 @@ class VectorizedFleetEngine:
                 cluster = self._query_cluster(i, link, reqs[i].dataset)
                 budget = cfg.max_samples
             env = self._make_tenant_env(reqs[i], i, shared)
+            if busy is not None:
+                env.transfer = busy.timed("netsim", env.transfer)
             env.clock_s = admit_time[i]
             envs[i] = env
             counter.admit(admit_time[i])
@@ -300,7 +311,15 @@ class VectorizedFleetEngine:
                 recovery=recovery,
             )
             gens[i] = sampler.session(env, reqs[i].dataset, cluster)
-            self._advance(i, gens, envs, reports, state, heap)
+            return i
+
+        admit = build if busy is None else busy.timed("admit", build)
+
+        def admit_next(now_s: float) -> None:
+            if pending:
+                self._advance(
+                    admit(now_s), gens, envs, reports, state, heap, step
+                )
 
         def enqueue_recovery(i: int, now_s: float) -> None:
             nonlocal n_kills, n_recoveries
@@ -370,8 +389,15 @@ class VectorizedFleetEngine:
                 gens[i] = None
                 envs[i] = None  # free generator frame + env at scale
                 continue
-            self._advance(i, gens, envs, reports, state, heap)
+            self._advance(i, gens, envs, reports, state, heap, step)
 
+        self.counters = None
+        if busy is not None:
+            self.counters = {
+                "events": self.events_processed - events0,
+                "admissions": busy.totals["admit"][1],
+                **{f"{k}_ns": ns for k, (ns, _) in busy.totals.items()},
+            }
         return assemble_fleet_report(
             self.db,
             cfg.testbed,
@@ -402,17 +428,17 @@ class VectorizedFleetEngine:
 
     # ------------------------------------------------------------------ #
     @staticmethod
-    def _advance(i, gens, envs, reports, state, heap) -> None:
+    def _advance(i, gens, envs, reports, state, heap, step=next) -> None:
         """Resume slot ``i``'s generator through exactly one interaction.
 
         The generator performs the environment interaction it announced with
         its previous yield, then either announces the next one (re-queue at
         its new clock) or returns its ``TransferReport`` (queue the finish
         event at the session's final clock — the same key as the threaded
-        worker's final turn).
+        worker's final turn).  ``step`` is ``next``, or ``next`` timed.
         """
         try:
-            t, phase, prm = next(gens[i])
+            t, phase, prm = step(gens[i])
         except StopIteration as stop:
             reports[i] = stop.value
             state.phase[i] = PHASE_FINISH

@@ -38,6 +38,7 @@ import threading
 
 import numpy as np
 
+from repro import obs
 from repro.core.offline import OfflineDB
 from repro.core.online import (
     AdaptiveSampler,
@@ -266,19 +267,23 @@ def predict_demands(
     Pallas kernel).  Demand is a pure function of the cluster — the
     candidate set is the cluster's own argmax points — so each group is
     scored once and broadcast to its requests.  The median-load surface's
-    best candidate is what the admission controller budgets against.
+    best candidate is what the admission controller budgets against.  The
+    whole of it, routing and scoring up to the host's read of each result,
+    is the trace span ``repro.fleet.score``.
     """
     link = TESTBEDS[testbed]
     demands = np.zeros(len(requests))
     groups: dict[int, list[int]] = {}
-    for i, req in enumerate(requests):
-        k = db.cluster_model.assign(request_features(link, req.dataset))
-        groups.setdefault(int(k), []).append(i)
-    for k, idxs in groups.items():
-        stack = db.clusters[k].surface_stack(db.bounds)
-        cand = stack.argmax_pts[None, :, :]  # one batch row per cluster
-        best, _ = stack.best_candidates(cand, use_pallas=use_pallas)
-        demands[idxs] = float(np.asarray(best)[0, stack.n_surfaces // 2])
+    with obs.span("fleet.score") as span:
+        for i, req in enumerate(requests):
+            k = db.cluster_model.assign(request_features(link, req.dataset))
+            groups.setdefault(int(k), []).append(i)
+        for k, idxs in groups.items():
+            stack = db.clusters[k].surface_stack(db.bounds)
+            cand = stack.argmax_pts[None, :, :]  # one batch row per cluster
+            best, _ = stack.best_candidates(cand, use_pallas=use_pallas)
+            demands[idxs] = float(np.asarray(best)[0, stack.n_surfaces // 2])
+        span.set_metadata(clusters=len(groups))
     return demands
 
 
