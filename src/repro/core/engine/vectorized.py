@@ -278,10 +278,12 @@ class VectorizedFleetEngine:
         )
         n_kills = 0
         n_recoveries = 0
+        n_const_load = 0  # admissions on constant traffic, counted traced only
 
         def build(now_s: float) -> int:
             """Admit the next queued request into its slot, up to the
             session's first step."""
+            nonlocal n_const_load
             i = pending.popleft()
             admit_time[i] = max(reqs[i].start_clock_s, now_s)
             state.admit_s[i] = admit_time[i]
@@ -299,6 +301,8 @@ class VectorizedFleetEngine:
             env = self._make_tenant_env(reqs[i], i, shared)
             if busy is not None:
                 env.transfer = busy.timed("netsim", env.transfer)
+                if getattr(env.traffic, "is_constant", False):
+                    n_const_load += 1
             env.clock_s = admit_time[i]
             envs[i] = env
             counter.admit(admit_time[i])
@@ -396,6 +400,7 @@ class VectorizedFleetEngine:
             self.counters = {
                 "events": self.events_processed - events0,
                 "admissions": busy.totals["admit"][1],
+                "const_load": n_const_load,
                 **{f"{k}_ns": ns for k, (ns, _) in busy.totals.items()},
             }
         return assemble_fleet_report(

@@ -26,15 +26,30 @@ class DiurnalTraffic:
     seed: int = 0
 
     def __post_init__(self):
-        self._rng = np.random.default_rng(self.seed)
+        # Built on the first draw, so a traffic without jitter never builds one.
+        self._rng: np.random.Generator | None = None
         self._walk = 0.0
 
+    @property
+    def is_constant(self) -> bool:
+        """No jitter and no peak: the load is ``base_load`` at every instant."""
+        return self.jitter == 0.0 and self.peak_load == 0.0
+
     def load_at(self, t_s: float) -> float:
+        if self.jitter == 0.0:
+            # normal(0, 0) is exactly +0.0, so there is nothing to draw
+            self._walk = 0.98 * self._walk + 0.0
+        else:
+            if self._rng is None:
+                self._rng = np.random.default_rng(self.seed)
+            self._walk = 0.98 * self._walk + self._rng.normal(0.0, self.jitter)
+        if self.peak_load == 0.0 and self._walk == 0.0:
+            # the diurnal term is 0.0 and the walk +0.0: the sum is base_load's
+            return float(min(max(self.base_load + self._walk, 0.0), 0.95))
         hour = (t_s % DAY_S) / 3600.0
         # circular distance to the peak hour
         d = min(abs(hour - self.peak_hour), 24.0 - abs(hour - self.peak_hour))
         diurnal = self.peak_load * math.exp(-0.5 * (d / self.peak_width_h) ** 2)
-        self._walk = 0.98 * self._walk + self._rng.normal(0.0, self.jitter)
         load = self.base_load + diurnal + self._walk
         return float(min(max(load, 0.0), 0.95))
 
@@ -45,8 +60,7 @@ class DiurnalTraffic:
 
     @staticmethod
     def constant(load: float) -> "DiurnalTraffic":
-        t = DiurnalTraffic(base_load=load, peak_load=0.0, jitter=0.0)
-        return t
+        return DiurnalTraffic(base_load=load, peak_load=0.0, jitter=0.0)
 
 
 @dataclasses.dataclass

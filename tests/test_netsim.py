@@ -1,11 +1,16 @@
 """Network-simulator invariants the paper's assumptions rely on."""
 
+import math
+
+import numpy as np
+import pytest
 from _hypothesis_compat import given, settings, st
 
 from repro.netsim import (
     make_testbed, make_dataset, ParamBounds, TransferParams, DiurnalTraffic,
     generate_history,
 )
+from repro.netsim.testbeds import _TRAFFIC, make_traffic
 
 B = ParamBounds()
 
@@ -81,6 +86,73 @@ def test_diurnal_traffic_peak_structure():
     assert noon > night + 0.3
     assert tr.is_peak(13 * 3600.0)
     assert not tr.is_peak(3 * 3600.0)
+
+
+class _DrawEveryReading:
+    """``DiurnalTraffic.load_at`` as first written: a generator built up
+    front and one draw per reading, even a draw of zero variance."""
+
+    def __init__(self, tr):
+        self.tr = tr
+        self.rng = np.random.default_rng(tr.seed)
+        self.walk = 0.0
+
+    def load_at(self, t_s):
+        tr = self.tr
+        hour = (t_s % (24 * 3600.0)) / 3600.0
+        d = min(abs(hour - tr.peak_hour), 24.0 - abs(hour - tr.peak_hour))
+        diurnal = tr.peak_load * math.exp(-0.5 * (d / tr.peak_width_h) ** 2)
+        self.walk = 0.98 * self.walk + self.rng.normal(0.0, tr.jitter)
+        load = tr.base_load + diurnal + self.walk
+        return float(min(max(load, 0.0), 0.95))
+
+
+_CASES = [pytest.param(lambda load=load: DiurnalTraffic.constant(load),
+                       id=f"constant-{load}")
+          for load in (0.0, 0.15, 0.5, 0.95, 1.2)] + [
+    pytest.param(lambda name=name, seed=seed: make_traffic(name, seed=seed),
+                 id=f"{name}-seed{seed}")
+    for name in _TRAFFIC for seed in (0, 7, 2**31 + 5)]
+
+
+@pytest.mark.parametrize("make", _CASES)
+def test_traffic_readings_are_bit_identical_to_a_draw_per_reading(make):
+    tr, ref = make(), _DrawEveryReading(make())
+    # a little over a day in uneven steps, through the peak and past midnight
+    times = [3 * 3600.0 + 97.3 * k for k in range(1000)]
+    assert [tr.load_at(t) for t in times] == [ref.load_at(t) for t in times]
+
+
+@pytest.fixture
+def made(monkeypatch):
+    """The arguments of every ``np.random.default_rng`` call from here on."""
+    calls = []
+    real = np.random.default_rng
+
+    def counted(*args, **kwargs):
+        calls.append(args)
+        return real(*args, **kwargs)
+
+    monkeypatch.setattr(np.random, "default_rng", counted)
+    return calls
+
+
+def test_constant_traffic_builds_no_generator(made):
+    env = make_testbed("xsede", seed=3, constant_load=0.15)
+    assert env.traffic.is_constant
+    loads = {env.traffic.load_at(60.0 * k) for k in range(100)}
+    assert loads == {0.15}
+    assert made == [(3,)]  # the environment's own noise generator alone
+
+
+def test_jittered_traffic_builds_its_generator_on_the_first_draw(made):
+    tr = make_traffic("xsede", seed=4)
+    assert not tr.is_constant and made == []
+    tr.load_at(0.0)
+    assert made == [(4 + 17,)]
+    for k in range(100):
+        tr.load_at(60.0 * k)
+    assert made == [(4 + 17,)]
 
 
 def test_transfer_session_reuse_skips_setup():

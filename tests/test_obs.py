@@ -9,6 +9,7 @@ bit-identical with a profiler session open and with none, and a run with
 tracing off reads no clock at all.
 """
 
+import dataclasses
 import glob
 
 import jax
@@ -143,6 +144,18 @@ def test_session_steps_hold_the_simulated_network(db, tmp_path):
     assert run["admit_ns"] > 0
     _, r0, r1, _ = _one(events, "repro.fleet.run")
     assert run["step_ns"] + run["admit_ns"] <= r1 - r0
+
+
+@pytest.mark.parametrize("load", [0.15, None], ids=["constant", "diurnal"])
+def test_const_load_counts_admissions_on_constant_traffic(db, tmp_path, load):
+    reqs = [dataclasses.replace(r, constant_load=load) for r in _requests(8)]
+    engine = VectorizedFleetEngine(db, _config())
+    engine.run(reqs)
+    assert engine.counters is None  # no profiler session: no counters
+    _, events = _traced(tmp_path, lambda: run_fleet(db, reqs, _config()))
+    run = _one(events, "repro.fleet.run")[3]
+    assert run["admissions"] == 8
+    assert run["const_load"] == (8 if load is not None else 0)
 
 
 def test_the_strict_sharded_regime_carries_the_counters(db, tmp_path):
