@@ -5,13 +5,24 @@ mines it into an ``OfflineDB`` with ``TransferTuner.fit``, and runs one
 warm-up fleet, which compiles the admission-scoring programs.  The history
 and the tuner take their seeds from the configuration, so every run serves
 from the same knowledge; the run's ``--seed`` draws the fleets.  The window
-then runs fleets of ``sessions`` requests back to back, each drawn afresh,
-all queued at ``start_clock_s`` of simulated time.
+then runs fleets of ``sessions`` requests back to back, each drawn afresh.
 
-``run_fleet`` takes its defaults except two settings: the vectorized
-engine (the threaded default is the test oracle) and no scoring against
-the single-tenant optimum, an evaluation oracle that grid-searches the
-simulator once per request and is not part of serving a transfer.
+The configuration names the testbed whose link the fleets share.  The
+traffic's ``arrivals`` say when requests arrive: absent or null, all are
+queued at ``start_clock_s`` of simulated time; else ``{"offered_load"}``,
+Poisson arrivals from ``start_clock_s`` at the rate that offers
+``offered_load`` of the link's service bound, the lesser of its bandwidth
+and its disks, in expected request size (``gen.poisson_arrivals``).  The
+testbed's external load is not taken off that bound: where it varies by
+the hour, the busy hours offer more than ``offered_load`` of what is left.
+``constant_load`` pins each tenant's external load; null leaves the
+testbed's diurnal load.
+
+``run_fleet`` takes its defaults except three settings: the configuration's
+testbed, the vectorized engine (the threaded default is the test oracle)
+and no scoring against the single-tenant optimum, an evaluation oracle
+that grid-searches the simulator once per request and is not part of
+serving a transfer.
 
 The check holds every fleet of the window to the guarantees of
 ``reference.fleet``, with the knowledge as set-up left it.
@@ -29,14 +40,32 @@ from benchmarks.chip.reference import fleet as ref
 # keyword sets of ``control``: two paths of the program that break a
 # guarantee, then faults planted in what a window's fleet reported
 CONTROLS = [{"kills": 8}, {"cap_shift": 1}, {"fault": "cap_reported_low"},
-            {"fault": "over_link"}, {"fault": "params_shifted"}]
+            {"fault": "over_link"}, {"fault": "params_shifted"},
+            {"fault": "admitted_early"}, {"fault": "queue_jumped"}]
+# entropy word of the arrival process's own stream, beside the run seed
+ARRIVALS_STREAM = 0xA441
 
 
 class State:
-    def __init__(self, config, traffic, db, knowledge, fleet_seeds):
+    def __init__(self, config, traffic, db, knowledge, fleet_seeds,
+                 engine=None):
         self.config, self.traffic = config, traffic
         self.db, self.knowledge = db, knowledge
         self.fleet_seeds = fleet_seeds
+        self.engine = engine  # the ``EngineConfig`` of the window's fleets
+
+
+def arrivals(config: dict, traffic: dict, n: int, seed: int) -> np.ndarray:
+    """Arrival times (simulated s) of one fleet's ``n`` requests."""
+    t0 = traffic["start_clock_s"]
+    a = traffic.get("arrivals")
+    if a is None:
+        return np.full(n, t0)
+    link = config["link"]
+    rate = (a["offered_load"] * min(link["bandwidth_mbps"], link["disk_mbps"])
+            / (8.0 * gen.mean_request_mb(config, traffic["classes"])))
+    return t0 + gen.poisson_arrivals(
+        n, rate, np.random.SeedSequence([seed, ARRIVALS_STREAM]))
 
 
 def requests(config: dict, traffic: dict, seed: int):
@@ -47,18 +76,20 @@ def requests(config: dict, traffic: dict, seed: int):
     n = traffic["sessions"]
     ds = gen.datasets(config, traffic["classes"], n, seed)
     env_seeds = np.random.default_rng(seed ^ 0x5EED).integers(2**31, size=n)
+    at = arrivals(config, traffic, n, seed)
     return [FleetRequest(
         dataset=Dataset(f"{fc}-{i}", fc, avg, nf),
         env_seed=int(es),
-        start_clock_s=traffic["start_clock_s"],
+        start_clock_s=float(t),
         constant_load=traffic["constant_load"],
-    ) for i, ((fc, avg, nf), es) in enumerate(zip(ds, env_seeds))]
+    ) for i, ((fc, avg, nf), es, t) in enumerate(zip(ds, env_seeds, at))]
 
 
-def engine_config(**extra):
+def engine_config(config: dict, **extra):
     from repro.core import EngineConfig
 
-    return EngineConfig(engine="vectorized", score_vs_single=False, **extra)
+    return EngineConfig(engine="vectorized", testbed=config["testbed"],
+                        score_vs_single=False, **extra)
 
 
 def knowledge(db) -> dict:
@@ -89,18 +120,19 @@ def setup(config: dict, traffic: dict, seed: int, spans) -> State:
             seed=s_hist)
     with spans.span("setup.fit"):
         db = TransferTuner(TunerConfig(seed=s_tuner % 2**31)).fit(hist).db
+    engine = engine_config(config)
     with spans.span("setup.warm"):
-        run_fleet(db, requests(config, traffic, s_warm), engine_config())
+        run_fleet(db, requests(config, traffic, s_warm), engine)
     return State(config, traffic, db, knowledge(db),
-                 np.random.default_rng(s_fleets))
+                 np.random.default_rng(s_fleets), engine)
 
 
-def run_one(state: State, seed: int, spans, config=None):
+def run_one(state: State, seed: int, spans, engine=None):
     from repro.core import run_fleet
 
     reqs = requests(state.config, state.traffic, seed)
     with spans.span("fleet.run_fleet"):
-        report = run_fleet(state.db, reqs, config or engine_config())
+        report = run_fleet(state.db, reqs, engine or state.engine)
     return reqs, report
 
 
@@ -136,9 +168,10 @@ def window(state: State, seconds: float, spans) -> dict:
 def plain(reqs, report) -> tuple[list, list, dict]:
     """The requests and the report as the plain numbers the reference reads."""
     return (
-        [{"avg_file_mb": r.dataset.avg_file_mb, "n_files": r.dataset.n_files}
-         for r in reqs],
-        [{"request": s.request_index, "admit_s": s.admit_s, "end_s": s.end_s,
+        [{"avg_file_mb": r.dataset.avg_file_mb, "n_files": r.dataset.n_files,
+          "arrival_s": r.start_clock_s} for r in reqs],
+        [{"request": s.request_index, "attempt": s.attempt,
+          "admit_s": s.admit_s, "end_s": s.end_s,
           "moved_mb": s.report.moved_mb,
           "achieved_mbps": s.report.achieved_mbps,
           "interrupted": s.report.interrupted,
@@ -175,10 +208,22 @@ def check(state: State, result: dict) -> list[tuple[str, float]]:
     return sorted(worst.items())
 
 
+def queued_pair(arrival_s: list[float], cap: int) -> tuple[int, int]:
+    """The requests of the first and the last queued admission: in arrival
+    order (ties by index), past the first ``cap``."""
+    order = sorted(range(len(arrival_s)), key=lambda i: (arrival_s[i], i))
+    if len(order) - cap < 2:
+        raise ValueError(
+            f"no queue to jump: {len(order)} requests under a cap of {cap} "
+            f"leave {max(len(order) - cap, 0)} queued, and a swap needs two")
+    return order[cap], order[-1]
+
+
 def _planted(answers, fault: str, bandwidth: float, max_cc: int):
     reqs, sessions, report = answers
     sessions = [dict(s) for s in sessions]
     report = dict(report)
+    first = {s["request"]: s for s in sessions if s["attempt"] == 0}
     if fault == "cap_reported_low":
         report["admitted_concurrency"] -= 1
     elif fault == "over_link":
@@ -189,6 +234,16 @@ def _planted(answers, fault: str, bandwidth: float, max_cc: int):
             if s["params"] is not None:
                 cc, p, pp = s["params"]
                 s["params"] = (cc + 1 if cc < max_cc else cc - 1, p, pp)
+    elif fault == "admitted_early":
+        # the last request to arrive admitted a second before it arrived
+        last = max(range(len(reqs)), key=lambda i: (reqs[i]["arrival_s"], i))
+        first[last]["admit_s"] = reqs[last]["arrival_s"] - 1.0
+    elif fault == "queue_jumped":
+        # the first and the last queued request admitted in each other's turn
+        a, b = queued_pair([r["arrival_s"] for r in reqs],
+                           report["admitted_concurrency"])
+        first[a]["admit_s"], first[b]["admit_s"] = (first[b]["admit_s"],
+                                                    first[a]["admit_s"])
     else:
         raise ValueError(f"unknown fault {fault!r}")
     return reqs, sessions, report
@@ -198,9 +253,10 @@ def control(state: State, result: dict, kills: int = 0, cap_shift: int = 0,
             fault: str | None = None) -> list[tuple[str, float]]:
     """Readings of one fleet of the cell's size that breaks a guarantee.
 
-    ``kills``: fault injection switched on with recovery off, so ``kills``
-    sessions die mid-transfer.  ``cap_shift``: the admission cap set
-    ``cap_shift`` above the one the window's first fleet was given.
+    ``kills``: fault injection switched on with recovery off, so every
+    session in flight dies at each of ``kills`` instants.  ``cap_shift``:
+    the admission cap set ``cap_shift`` above the one the window's first
+    fleet was given.
     ``fault``: a fault planted in the window's first fleet's answers.
     """
     from repro.netsim import FaultSchedule, TenantKill
@@ -211,15 +267,18 @@ def control(state: State, result: dict, kills: int = 0, cap_shift: int = 0,
                            state.config["param_domain"]["cc"])
     else:
         extra = {}
+        seed = int(state.fleet_seeds.integers(2**62))
         if kills:
-            t0 = state.traffic["start_clock_s"]
+            # the k-th kill 30 (k + 1) s after the k-th arrival
+            at = arrivals(state.config, state.traffic,
+                          state.traffic["sessions"], seed)
             extra["faults"] = FaultSchedule(tuple(
-                TenantKill(at_s=t0 + 30.0 * (k + 1), tenant_id=None)
+                TenantKill(at_s=float(at[min(k, len(at) - 1)])
+                           + 30.0 * (k + 1), tenant_id=None)
                 for k in range(kills)))
         if cap_shift:
             extra["max_concurrent"] = (first[2]["admitted_concurrency"]
                                        + cap_shift)
-        seed = int(state.fleet_seeds.integers(2**62))
         answers = plain(*run_one(state, seed, clock.Spans(),
-                                 engine_config(**extra)))
+                                 engine_config(state.config, **extra)))
     return sorted(readings(state, _reference(state), answers).items())

@@ -63,3 +63,22 @@ def datasets(config: dict, classes: list[str], n: int,
         out.append((fc, avg, nf))
     return out
 
+
+def mean_request_mb(config: dict, classes: list[str]) -> float:
+    """Expected size of one request (MB) under :func:`datasets`: the mean
+    over the classes, each the log-uniform mean of its mean file size times
+    the uniform mean of its file count."""
+    out = 0.0
+    for fc in classes:
+        spec = config["file_classes"][fc]
+        lo, hi = spec["avg_file_mb"]
+        avg = lo if hi == lo else (hi - lo) / np.log(hi / lo)
+        out += avg * 0.5 * (spec["n_files"][0] + spec["n_files"][1])
+    return float(out / len(classes))
+
+
+def poisson_arrivals(n: int, rate: float, seed) -> np.ndarray:
+    """``n`` arrival offsets (s, ascending, from 0) of a Poisson process of
+    ``rate`` arrivals per second.  ``seed`` is anything
+    ``np.random.default_rng`` takes."""
+    return np.cumsum(np.random.default_rng(seed).exponential(1.0 / rate, n))

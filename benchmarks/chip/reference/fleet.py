@@ -19,7 +19,11 @@ numbers), and imports nothing of the program:
   integer lattice, the cap ``overcommit * bandwidth / median demand``;
 * ``param_gap``       -- largest shortfall, as a share of the surface's
   best, of a session's parameters from the lattice optimum of the surface
-  of its cluster that they come closest to optimising.
+  of its cluster that they come closest to optimising;
+* ``early_admits``    -- sessions admitted before their request arrived;
+* ``queue_gap``       -- largest distance of a request's admission from the
+  one that first-come-first-served, work-conserving admission under the
+  reported cap gives (:func:`fifo_admissions`).
 
 The knowledge is each cluster's centroid and its throughput surfaces, each
 a natural cubic spline through a grid of throughputs over parallelism,
@@ -27,6 +31,7 @@ concurrency and pipelining knots, the end pieces extended past the knots.
 """
 from __future__ import annotations
 
+import heapq
 import math
 
 import numpy as np
@@ -45,6 +50,43 @@ def _max_concurrent(intervals: list[tuple[float, float]]) -> int:
         live += 1 if kind else -1
         best = max(best, live)
     return best
+
+
+def fifo_admissions(arrival_s: list[float], end_s: dict[int, float],
+                    cap: int) -> dict[int, float]:
+    """Admission time of each request in ``end_s`` under first-come-first-
+    served admission that never idles a free slot below ``cap``.
+
+    In arrival order (ties by index), the first ``cap`` requests are
+    admitted when they arrive; each later one when it arrives or when the
+    earliest end still holding a slot frees it, whichever is later; then
+    its own end holds the slot.  ``end_s`` maps a request to the end of its
+    first session.  Recovery re-admissions are left out: they queue behind
+    every first request, so they move no first admission.  A request with
+    no session is left out too (``lost_requests`` counts it).
+    """
+    order = sorted(end_s, key=lambda i: (arrival_s[i], i))
+    held: list[float] = []
+    out: dict[int, float] = {}
+    for k, i in enumerate(order):
+        out[i] = (arrival_s[i] if k < cap
+                  else max(arrival_s[i], heapq.heappop(held)))
+        heapq.heappush(held, end_s[i])
+    return out
+
+
+def admission(requests: list[dict], sessions: list[dict],
+              cap: int) -> dict[str, float]:
+    """``early_admits`` and ``queue_gap`` of a fleet whose reported cap is
+    ``cap`` (the arguments as :func:`compare` takes them)."""
+    arrival = [r["arrival_s"] for r in requests]
+    early = sum(1 for s in sessions if s["admit_s"] < arrival[s["request"]])
+    first = {s["request"]: s for s in sessions if s["attempt"] == 0}
+    fifo = fifo_admissions(arrival, {i: s["end_s"] for i, s in first.items()},
+                           cap)
+    gap = max((abs(first[i]["admit_s"] - t) for i, t in fifo.items()),
+              default=0.0)
+    return {"early_admits": float(early), "queue_gap": gap}
 
 
 def spline_operator(x, q) -> np.ndarray:
@@ -147,10 +189,11 @@ def admission_cap(demands: np.ndarray, link: dict, overcommit: float,
 
 def compare(requests: list[dict], sessions: list[dict], report: dict,
             link: dict, overcommit: float, know: Knowledge) -> dict[str, float]:
-    """``requests``: ``{"avg_file_mb", "n_files"}`` per request, in order.
-    ``sessions``: ``{"request", "admit_s", "end_s", "moved_mb",
-    "achieved_mbps", "interrupted", "params"}`` per session attempt, with
-    ``params`` as ``(cc, p, pp)`` or ``None``.  ``report``:
+    """``requests``: ``{"avg_file_mb", "n_files", "arrival_s"}`` per
+    request, in order.  ``sessions``: ``{"request", "attempt", "admit_s",
+    "end_s", "moved_mb", "achieved_mbps", "interrupted", "params"}`` per
+    session attempt (``attempt`` 0 for the first), with ``params`` as
+    ``(cc, p, pp)`` or ``None``.  ``report``:
     ``{"goodput_mbps", "admitted_concurrency"}``."""
     bandwidth = link["bandwidth_mbps"]
     served = {s["request"] for s in sessions if not s["interrupted"]}
@@ -187,4 +230,5 @@ def compare(requests: list[dict], sessions: list[dict], report: dict,
         "goodput_rel_gap": gap,
         "cap_gap": float(cap_gap),
         "param_gap": max(param_gap, 0.0),
+        **admission(requests, sessions, got),
     }
