@@ -66,6 +66,7 @@ from repro.core.fleet import (
     ReprobeLimiter,
     assemble_fleet_report,
     auto_concurrency,
+    with_link_load,
 )
 from repro.core.online import AdaptiveSampler, request_features
 from repro.core.refresh import KnowledgeRefresher
@@ -177,6 +178,7 @@ class ShardedFleetEngine(VectorizedFleetEngine):
 
     # ------------------------------------------------------------------ #
     def run(self, requests: list[FleetRequest]) -> FleetReport:
+        requests = with_link_load(requests, self.config.testbed)
         self._precompute_admissions(requests)
         window = self._window_s(len(requests))
         if window is None:
@@ -261,16 +263,15 @@ class ShardedFleetEngine(VectorizedFleetEngine):
             else:
                 cluster = self._query_cluster(i, link, reqs[i].dataset)
                 budget = cfg.max_samples
-            if reqs[i].traffic is not None:
-                traffic = reqs[i].traffic
-            elif reqs[i].constant_load is not None:
+            # every request has a traffic or a constant load here
+            # (``with_link_load``)
+            traffic = reqs[i].traffic
+            if traffic is None:
                 load = float(reqs[i].constant_load)
                 traffic = const_traffic.get(load)
                 if traffic is None:
                     traffic = make_traffic(cfg.testbed, constant_load=load)
                     const_traffic[load] = traffic
-            else:
-                traffic = make_traffic(cfg.testbed, seed=reqs[i].env_seed)
             env = WindowTenantEnvironment(
                 link,
                 traffic,

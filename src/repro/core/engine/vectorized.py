@@ -49,6 +49,7 @@ from repro.core.fleet import (
     ReprobeLimiter,
     assemble_fleet_report,
     auto_concurrency,
+    with_link_load,
 )
 from repro.core.offline import OfflineDB
 from repro.core.online import (
@@ -225,6 +226,7 @@ class VectorizedFleetEngine:
         n = len(requests)
         if n == 0:
             return FleetReport([], 0.0, 0.0, 0.0, 0.0, 0.0, 0, 0, 0)
+        requests = with_link_load(requests, cfg.testbed)
         # Busy-time counters only while a profiler session records; with
         # tracing off the loop calls the plain callables and reads no clock.
         busy = obs.Busy() if obs.active() else None
@@ -303,6 +305,11 @@ class VectorizedFleetEngine:
                 env.transfer = busy.timed("netsim", env.transfer)
                 if getattr(env.traffic, "is_constant", False):
                     n_const_load += 1
+                else:
+                    # the shared link load's reading, inside the
+                    # transfer's; a constant load is left untimed, so the
+                    # timer adds nothing to its fleets' netsim_ns
+                    env.current_load = busy.timed("load", env.current_load)
             env.clock_s = admit_time[i]
             envs[i] = env
             counter.admit(admit_time[i])
@@ -401,6 +408,8 @@ class VectorizedFleetEngine:
                 "events": self.events_processed - events0,
                 "admissions": busy.totals["admit"][1],
                 "const_load": n_const_load,
+                "reprobe_grants": limiter.grants,
+                "load_ns": 0,  # no shared load: every tenant's is constant
                 **{f"{k}_ns": ns for k, (ns, _) in busy.totals.items()},
             }
         return assemble_fleet_report(

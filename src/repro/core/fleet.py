@@ -48,7 +48,7 @@ from repro.core.online import (
 )
 from repro.core.refresh import KnowledgeRefresher, RefreshConfig
 from repro.netsim.environment import Environment, SharedLink, TenantEnvironment
-from repro.netsim.testbeds import TESTBEDS, make_testbed
+from repro.netsim.testbeds import TESTBEDS, make_link_load, make_testbed
 from repro.netsim.workload import Dataset
 
 
@@ -59,7 +59,10 @@ class FleetRequest:
     ``traffic`` overrides the testbed's diurnal background-load model for
     this tenant's path; it must be stateless/deterministic (a pure function
     of simulated time, e.g. ``netsim.RegimeShiftTraffic``) so fleet runs
-    stay reproducible and instances can be shared across tenants.
+    stay reproducible and instances can be shared across tenants.  A
+    request with neither ``traffic`` nor ``constant_load`` runs under the
+    link's one diurnal load, which every engine builds once per fleet
+    (:func:`with_link_load`).
     """
 
     dataset: Dataset
@@ -245,6 +248,28 @@ class _FleetClock:
                 self._in_flight = None
                 self._clocks[tid] = env.clock_s
                 self._wake_next()
+
+
+def with_link_load(
+    requests: list[FleetRequest], testbed: str
+) -> list[FleetRequest]:
+    """``requests``, each with neither ``traffic`` nor ``constant_load``
+    given the link's one external load as its ``traffic``.
+
+    The load is ``netsim.make_link_load(testbed)``, built once per fleet and
+    seeded from the ``env_seed`` of the fleet's request 0 (in list order),
+    so every tenant of the link reads the same load at the same simulated
+    instant, and a fleet's load is a function of its requests alone.
+    """
+    if all(r.traffic is not None or r.constant_load is not None
+           for r in requests):
+        return requests
+    load = make_link_load(testbed, seed=requests[0].env_seed)
+    return [
+        r if r.traffic is not None or r.constant_load is not None
+        else dataclasses.replace(r, traffic=load)
+        for r in requests
+    ]
 
 
 # Single-tenant optima are pure functions of (testbed, seed, load, dataset,
@@ -502,6 +527,7 @@ class FleetScheduler:
         n = len(requests)
         if n == 0:
             return FleetReport([], 0.0, 0.0, 0.0, 0.0, 0.0, 0, 0, 0)
+        requests = with_link_load(requests, self.config.testbed)
         link = TESTBEDS[self.config.testbed]
         shared = SharedLink(link)
         clock = _FleetClock()

@@ -22,14 +22,19 @@ from repro.netsim.faults import SessionKilled
 from repro.netsim.workload import Dataset
 
 
-@dataclasses.dataclass
+@dataclasses.dataclass(slots=True)
 class SampleRecord:
+    """One probe or bulk chunk; ``clock_s`` is its simulated start and
+    ``ext_load`` the external load it ran under (``None`` where the runner
+    records neither).  Slotted: a fleet makes one per chunk."""
     params: TransferParams
     predicted: float
     achieved: float
     surface_load: float
     elapsed_s: float
     was_sample: bool
+    clock_s: float | None = None
+    ext_load: float | None = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -248,26 +253,29 @@ class AdaptiveSampler:
         region = cluster.region
         if len(surfaces) > 1 and region.discriminative_points:
             prm = region.discriminative_points[0]
-            yield env.clock_s, PHASE_PROBE, prm
+            t = env.clock_s
+            yield t, PHASE_PROBE, prm
             res = env.transfer(prm, probe_mb, dataset.avg_file_mb,
                                dataset.n_files, is_sample=True)
             achieved = res.steady_mbps
             cur = min(surfaces, key=lambda s: abs(s.predict(prm) - achieved))
             records.append(SampleRecord(prm, cur.predict(prm), achieved,
                                         cur.load_intensity, res.elapsed_s,
-                                        True))
+                                        True, t, res.ext_load))
             budget -= 1
 
         # --- Algorithm-1 loop over surface argmaxima ------------------- #
         for _ in range(budget):
             prm = cur.argmax_params
-            yield env.clock_s, PHASE_PROBE, prm
+            t = env.clock_s
+            yield t, PHASE_PROBE, prm
             res = env.transfer(prm, probe_mb, dataset.avg_file_mb,
                                dataset.n_files, is_sample=True)
             achieved = res.steady_mbps     # monitored steady rate, post-ramp
             predicted = cur.predict(prm)
             records.append(SampleRecord(prm, predicted, achieved,
-                                        cur.load_intensity, res.elapsed_s, True))
+                                        cur.load_intensity, res.elapsed_s, True,
+                                        t, res.ext_load))
             if cur.in_confidence(prm, achieved, self.z):
                 break                                # converged
             lighter = cur.above_band(prm, achieved, self.z)
@@ -347,7 +355,8 @@ class AdaptiveSampler:
             while chunks_left > 0:
                 if chunk_mb <= 0:
                     break
-                yield env.clock_s, PHASE_BULK, params
+                t = env.clock_s
+                yield t, PHASE_BULK, params
                 res = env.transfer(params, chunk_mb, dataset.avg_file_mb,
                                    dataset.n_files)
                 chunks_left -= 1
@@ -355,7 +364,8 @@ class AdaptiveSampler:
                 achieved = res.steady_mbps
                 records.append(SampleRecord(params, surface.predict(params),
                                             achieved, surface.load_intensity,
-                                            res.elapsed_s, False))
+                                            res.elapsed_s, False, t,
+                                            res.ext_load))
                 prev_rate = baseline
                 baseline = achieved
                 if not surface.in_confidence(params, achieved, self.z):

@@ -14,10 +14,12 @@ from repro.netsim.environment import (
     TenantEnvironment,
 )
 from repro.netsim.testbeds import (
-    make_testbed, XSEDE, DIDCLAB, DIDCLAB_XSEDE, TESTBEDS,
+    make_link_load, make_testbed, XSEDE, DIDCLAB, DIDCLAB_XSEDE, TESTBEDS,
 )
 from repro.netsim.workload import Dataset, make_dataset, FILE_CLASSES
-from repro.netsim.traffic import DiurnalTraffic, RegimeShiftTraffic, StepTraffic
+from repro.netsim.traffic import (
+    DiurnalLinkLoad, DiurnalTraffic, RegimeShiftTraffic, StepTraffic,
+)
 from repro.netsim.faults import (
     CapacityDrop, FaultSchedule, LinkFlap, LossBurst, SessionKilled,
     TenantKill,
@@ -29,9 +31,9 @@ from repro.netsim.loggen import (
 
 __all__ = [
     "Environment", "IndexedSharedLink", "TransferParams", "ParamBounds",
-    "SharedLink",
-    "TenantEnvironment", "make_testbed", "XSEDE", "DIDCLAB", "DIDCLAB_XSEDE",
-    "TESTBEDS", "Dataset", "make_dataset", "FILE_CLASSES", "DiurnalTraffic",
+    "SharedLink", "TenantEnvironment", "make_link_load", "make_testbed",
+    "XSEDE", "DIDCLAB", "DIDCLAB_XSEDE", "TESTBEDS", "Dataset",
+    "make_dataset", "FILE_CLASSES", "DiurnalLinkLoad", "DiurnalTraffic",
     "RegimeShiftTraffic", "StepTraffic", "generate_history", "LogEntry",
     "features_of", "generate_multi_network_history", "sample_feature_logs",
     "CapacityDrop", "FaultSchedule", "LinkFlap", "LossBurst", "SessionKilled",

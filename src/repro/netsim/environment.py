@@ -51,10 +51,13 @@ class TransferResult:
     setup/slow-start ramp — this is what tuners compare against model
     predictions.  ``effective_mbps`` divides megabits moved by total elapsed
     time including setup, i.e. what the end user experiences.
+    ``ext_load`` is the external load the chunk ran under, read once at
+    its start (``None`` from an environment that does not say).
     """
     effective_mbps: float
     steady_mbps: float
     elapsed_s: float
+    ext_load: float | None = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -262,7 +265,8 @@ class Environment:
         self.advance(elapsed)
         if is_sample:
             self.sample_count += 1
-        return TransferResult(float(effective), float(noisy), float(elapsed))
+        return TransferResult(float(effective), float(noisy), float(elapsed),
+                              load)
 
     def _transfer_faulted(self, params: TransferParams, size_mb: float,
                           avg_file_mb: float, n_files: int, *,
@@ -329,7 +333,8 @@ class Environment:
         self.advance(elapsed)
         if is_sample:
             self.sample_count += 1
-        return TransferResult(float(effective), float(steady), float(elapsed))
+        return TransferResult(float(effective), float(steady), float(elapsed),
+                              load)
 
     def measure_steady(self, params: TransferParams, avg_file_mb: float,
                        n_files: int) -> float:

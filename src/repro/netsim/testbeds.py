@@ -10,7 +10,7 @@
 from __future__ import annotations
 
 from repro.netsim.environment import Environment, LinkSpec
-from repro.netsim.traffic import DiurnalTraffic
+from repro.netsim.traffic import DiurnalLinkLoad, DiurnalTraffic
 
 XSEDE = LinkSpec(
     name="xsede",
@@ -75,6 +75,13 @@ def make_traffic(name: str, *, seed: int = 0,
     if constant_load is not None:
         return DiurnalTraffic.constant(constant_load)
     return DiurnalTraffic(seed=seed + 17, **_TRAFFIC[name])
+
+
+def make_link_load(name: str, *, seed: int) -> DiurnalLinkLoad:
+    """The one external load of testbed ``name``'s link, shared by every
+    tenant on it: the testbed's diurnal parameters, the walk on the
+    default grid drawn from ``seed``."""
+    return DiurnalLinkLoad(seed=seed, **_TRAFFIC[name])
 
 
 def make_testbed(name: str, *, seed: int = 0,

@@ -13,6 +13,15 @@ import math
 import numpy as np
 
 DAY_S = 24 * 3600.0
+#: AR(1) coefficient of a link load's walk, DiurnalTraffic's 0.98
+WALK_AR = 0.98
+#: entropy word of a link load's walk, beside its seed
+LOAD_STREAM = 0x10AD
+#: Grid step of a link load's walk (simulated s): the mean spacing of one
+#: session's chunks on DIDCLAB under Poisson arrivals from 08:00 with a
+#: walk per tenant, 137 s, rounded, so the shared walk keeps about the
+#: pace each tenant's own walk had.
+WALK_STEP_S = 140.0
 
 
 @dataclasses.dataclass
@@ -61,6 +70,79 @@ class DiurnalTraffic:
     @staticmethod
     def constant(load: float) -> "DiurnalTraffic":
         return DiurnalTraffic(base_load=load, peak_load=0.0, jitter=0.0)
+
+
+@dataclasses.dataclass(frozen=True)
+class DiurnalLinkLoad:
+    """One link's external load: the diurnal term of :class:`DiurnalTraffic`
+    plus its AR(1) walk, as a pure function of simulated time.
+
+    The walk is laid on a fixed grid of ``WALK_STEP_S`` simulated seconds:
+    ``walk[0] = 0`` and ``walk[k] = 0.98 * walk[k - 1] + e_k``, the ``e_k``
+    drawn in order from ``numpy.random.default_rng([seed, LOAD_STREAM])``
+    with sd ``jitter``.  The load at ``t`` is::
+
+        clip(base_load + diurnal(t) + walk[max(floor(t / WALK_STEP_S), 0)],
+             0, 0.95)
+
+    so it is piecewise constant in the walk and continuous in the diurnal
+    term.  The walk is extended forward in blocks and memoised: a reading
+    does not depend on the order or number of readings before it, so one
+    instance serves every tenant of a link, and two instances of one seed
+    agree.  ``DiurnalTraffic``, whose walk steps once per reading, stays as
+    it was for the single-environment history.
+    """
+    base_load: float = 0.10
+    peak_load: float = 0.55
+    peak_hour: float = 13.0
+    peak_width_h: float = 4.0
+    jitter: float = 0.04
+    seed: int = 0
+
+    _BLOCK = 256  # innovations drawn per extension of the walk
+
+    def __post_init__(self):
+        # the memo of the walk is state of the instance, not a field
+        object.__setattr__(self, "_walk", [0.0])
+        object.__setattr__(self, "_rng", None)
+
+    def _extend(self, k: int) -> None:
+        """Extend the walk through grid index ``k``."""
+        walk = self._walk
+        if self._rng is None:
+            object.__setattr__(self, "_rng", np.random.default_rng(
+                [self.seed, LOAD_STREAM]))
+        w = walk[-1]
+        while len(walk) <= k:
+            for e in self._rng.normal(0.0, self.jitter,
+                                      size=self._BLOCK).tolist():
+                w = WALK_AR * w + e
+                walk.append(w)
+
+    def walk_at(self, t_s: float) -> float:
+        """The walk's value over the grid step that holds ``t_s``."""
+        k = max(math.floor(t_s / WALK_STEP_S), 0)
+        if k >= len(self._walk):
+            self._extend(k)
+        return self._walk[k]
+
+    def load_at(self, t_s: float) -> float:
+        # the arithmetic of DiurnalTraffic.load_at, with comparisons in
+        # place of min/max: a reading per chunk is on the fleet's hot path
+        hour = (t_s % DAY_S) / 3600.0
+        d = abs(hour - self.peak_hour)
+        if d > 12.0:  # circular distance to the peak hour
+            d = 24.0 - d
+        k = math.floor(t_s / WALK_STEP_S)
+        if k < 0:
+            k = 0
+        walk = self._walk
+        if k >= len(walk):
+            self._extend(k)
+        load = (self.base_load
+                + self.peak_load * math.exp(-0.5 * (d / self.peak_width_h) ** 2)
+                + walk[k])
+        return 0.0 if load < 0.0 else (0.95 if load > 0.95 else load)
 
 
 @dataclasses.dataclass

@@ -12,6 +12,7 @@ from repro.core import (
     TunerConfig,
 )
 from repro.netsim import (
+    Environment,
     SharedLink,
     StepTraffic,
     TenantEnvironment,
@@ -19,6 +20,7 @@ from repro.netsim import (
     XSEDE,
     generate_history,
     make_dataset,
+    make_link_load,
     make_testbed,
 )
 
@@ -35,7 +37,12 @@ def db():
 def _single_tenant_report(db, ds, seed, constant_load=None):
     from repro.core.online import AdaptiveSampler
 
-    env = make_testbed("xsede", seed=seed, constant_load=constant_load)
+    if constant_load is None:
+        # a lone tenant under the link's one load, which a fleet seeds from
+        # its request 0's env_seed
+        env = Environment(XSEDE, make_link_load("xsede", seed=seed), seed=seed)
+    else:
+        env = make_testbed("xsede", seed=seed, constant_load=constant_load)
     env.clock_s = START
     return AdaptiveSampler(db).transfer(env, ds), env.clock_s
 

@@ -147,6 +147,19 @@ def test_session_steps_hold_the_simulated_network(db, tmp_path):
 
 
 @pytest.mark.parametrize("load", [0.15, None], ids=["constant", "diurnal"])
+def test_load_readings_sit_in_the_network_and_grants_are_the_runs(
+        db, tmp_path, load):
+    reqs = [dataclasses.replace(r, constant_load=load) for r in _requests()]
+    report, events = _traced(tmp_path, lambda: run_fleet(db, reqs, _config()))
+    run = _one(events, "repro.fleet.run")[3]
+    if load is None:
+        assert run["netsim_ns"] >= run["load_ns"] > 0
+    else:  # a constant load is not timed
+        assert run["load_ns"] == 0 and run["netsim_ns"] > 0
+    assert run["reprobe_grants"] == report.reprobe_grants
+
+
+@pytest.mark.parametrize("load", [0.15, None], ids=["constant", "diurnal"])
 def test_const_load_counts_admissions_on_constant_traffic(db, tmp_path, load):
     reqs = [dataclasses.replace(r, constant_load=load) for r in _requests(8)]
     engine = VectorizedFleetEngine(db, _config())
