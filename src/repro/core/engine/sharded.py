@@ -33,18 +33,15 @@ count is the host's device count) and runs in one of two regimes:
   buys the multi-shard sessions/s scaling (``benchmarks/fleet_shard.py``).
 
 Both regimes funnel their report through the shared
-``assemble_fleet_report`` and batch admission routing through
-``ClusterModel.assign_many`` (default float64 path — arithmetic-identical
-to per-request ``assign``; the float32 Pallas path would break routing
-parity) whenever the knowledge base is frozen for the run.
+``assemble_fleet_report`` and, whenever the knowledge base is frozen for
+the run, admit each first attempt on the cluster the fleet's one routing
+pass gave it (``plan_admission``), as the vectorized engine does.
 """
 
 from __future__ import annotations
 
 import collections
 import dataclasses
-
-import numpy as np
 
 from repro.core.engine.shard import (
     ShardedEventFrontier,
@@ -59,13 +56,13 @@ from repro.core.engine.vectorized import (
     FleetStateArrays,
     VectorizedFleetEngine,
     _ActiveCounter,
+    plan_admission,
 )
 from repro.core.fleet import (
     FleetReport,
     FleetRequest,
     ReprobeLimiter,
     assemble_fleet_report,
-    auto_concurrency,
     with_link_load,
 )
 from repro.core.online import AdaptiveSampler, request_features
@@ -113,7 +110,6 @@ class ShardedFleetEngine(VectorizedFleetEngine):
         super().__init__(db, config)
         self.n_shards = self._resolve_n_shards(config)
         self.windows_run = 0
-        self._cluster_idx: np.ndarray | None = None
 
     # ------------------------------------------------------------------ #
     @staticmethod
@@ -132,39 +128,6 @@ class ShardedFleetEngine(VectorizedFleetEngine):
             return super()._make_heap(n)
         return ShardedEventFrontier(self.n_shards, capacity=max(2 * n, 16))
 
-    def _query_cluster(self, i: int, link, dataset):
-        idx = self._cluster_idx
-        if idx is not None and i < idx.shape[0]:
-            return self.db.clusters[int(idx[i])]
-        return super()._query_cluster(i, link, dataset)
-
-    def _precompute_admissions(self, requests: list[FleetRequest]) -> None:
-        """Batch the initial wave's cluster routing through ``assign_many``.
-
-        Only when the knowledge base is frozen for the run (no refresher,
-        no knowledge service) — a mid-run ``OfflineDB.update`` would
-        invalidate precomputed indices.  Always the default chunked float64
-        path, which is arithmetic-identical to per-request ``assign``
-        regardless of ``use_pallas`` (the Pallas path is float32 and would
-        break routing parity).  Recovery re-admissions occupy slots beyond
-        the initial wave and fall back to scalar ``db.query``.
-        """
-        cfg = self.config
-        self._cluster_idx = None
-        if cfg.refresh is not None or getattr(cfg, "knowledge", None) is not None:
-            return
-        model = getattr(self.db, "cluster_model", None)
-        if model is None or not requests:
-            return
-        link = TESTBEDS[cfg.testbed]
-        feats = np.stack(
-            [
-                np.asarray(request_features(link, r.dataset), np.float64)
-                for r in requests
-            ]
-        )
-        self._cluster_idx = np.asarray(model.assign_many(feats), np.int64)
-
     def _window_s(self, n: int) -> float | None:
         """Window width for this run, or ``None`` for the strict regime."""
         if self.n_shards <= 1:
@@ -179,7 +142,6 @@ class ShardedFleetEngine(VectorizedFleetEngine):
     # ------------------------------------------------------------------ #
     def run(self, requests: list[FleetRequest]) -> FleetReport:
         requests = with_link_load(requests, self.config.testbed)
-        self._precompute_admissions(requests)
         window = self._window_s(len(requests))
         if window is None:
             return super().run(requests)
@@ -217,14 +179,7 @@ class ShardedFleetEngine(VectorizedFleetEngine):
             else None
         )
         k_stats0 = knowledge.stats() if knowledge is not None else None
-        cap = cfg.max_concurrent or auto_concurrency(
-            self.db,
-            requests,
-            link,
-            testbed=cfg.testbed,
-            overcommit=cfg.overcommit,
-            use_pallas=cfg.use_pallas,
-        )
+        cap, routes = plan_admission(self.db, cfg, requests, link)
         recovery = cfg.recovery
 
         reqs: list[FleetRequest] = list(requests)
@@ -260,8 +215,11 @@ class ShardedFleetEngine(VectorizedFleetEngine):
                 budget = knowledge.probe_budget(
                     None, admit_time[i], cfg.max_samples
                 )
+            elif routes is not None and i < n:
+                cluster = self.db.clusters[routes[i]]
+                budget = cfg.max_samples
             else:
-                cluster = self._query_cluster(i, link, reqs[i].dataset)
+                cluster = self.db.query(request_features(link, reqs[i].dataset))
                 budget = cfg.max_samples
             # every request has a traffic or a constant load here
             # (``with_link_load``)

@@ -31,7 +31,10 @@ hundreds of sessions to 1e5+ (``benchmarks/fleet_scale.py``).
 
 The batched-kernel path is unchanged: admission demand prediction still goes
 through ``SurfaceStack.best_candidates`` (vmapped gather or the Pallas
-kernel) via the shared module-level ``predict_demands``.
+kernel) via the shared module-level ``auto_concurrency``.  Where the
+knowledge is frozen for the run, that pass's routing is also each first
+attempt's cluster at admission (:func:`plan_admission`), so a request is
+routed once per fleet.
 """
 
 from __future__ import annotations
@@ -64,7 +67,7 @@ from repro.netsim.environment import (
     SharedLink,
     TenantEnvironment,
 )
-from repro.netsim.testbeds import TESTBEDS, make_testbed
+from repro.netsim.testbeds import TESTBEDS, make_traffic
 
 # Slot phases: 1-3 mirror the ``AdaptiveSampler.session`` yield tags
 # (PHASE_PROBE / PHASE_BULK / PHASE_GATE); the engine adds the two
@@ -78,6 +81,28 @@ PHASE_FINISH = 4  # session returned; finish bookkeeping event is queued
 #: far below this line, so "auto" is both oracle-exact where it is checked
 #: and scalable where it matters.
 AUTO_CONTENTION_CUTOVER = 1024
+
+
+def plan_admission(db: OfflineDB, cfg, requests, link):
+    """``(cap, routes)`` of a run: the admission cap, and where the cap
+    was computed and the knowledge is frozen for the run (no refresher, no
+    knowledge service), the cluster index of each request from that same
+    pass, else ``None``.  Admission reads ``db.clusters[routes[i]]`` for a
+    first attempt ``i`` in place of ``db.query``: the same float64
+    nearest-centroid routing, done once.  Recovery re-admissions, which
+    have slots past the fleet's requests, still query."""
+    if cfg.max_concurrent:
+        return cfg.max_concurrent, None
+    plan = auto_concurrency(
+        db,
+        requests,
+        link,
+        testbed=cfg.testbed,
+        overcommit=cfg.overcommit,
+        use_pallas=cfg.use_pallas,
+    )
+    frozen = cfg.refresh is None and getattr(cfg, "knowledge", None) is None
+    return plan.cap, plan.clusters.tolist() if frozen else None
 
 
 @dataclasses.dataclass
@@ -187,13 +212,6 @@ class VectorizedFleetEngine:
         global ``(time, slot)`` order)."""
         return VectorEventHeap(capacity=max(2 * n, 16))
 
-    def _query_cluster(self, i: int, link, dataset):
-        """Admission-time cluster snapshot for slot ``i`` on the raw-DB path
-        (no knowledge service).  The sharded engine overrides this with a
-        batch-precomputed assignment when the DB is frozen for the run —
-        which is why feature extraction happens inside the hook."""
-        return self.db.query(request_features(link, dataset))
-
     def _make_shared(self, link, n: int):
         mode = getattr(self.config, "contention", "auto")
         if mode == "exact" or (mode == "auto" and n <= AUTO_CONTENTION_CUTOVER):
@@ -203,18 +221,20 @@ class VectorizedFleetEngine:
     def _make_tenant_env(
         self, req: FleetRequest, tenant_id: int, shared
     ) -> TenantEnvironment:
-        base = make_testbed(
-            self.config.testbed,
-            seed=req.env_seed,
-            constant_load=req.constant_load,
-        )
-        traffic = req.traffic if req.traffic is not None else base.traffic
+        # the link, traffic, seed and noise ``make_testbed`` gives the
+        # threaded oracle's tenants, with no environment built to read them
+        traffic = req.traffic
+        if traffic is None:
+            traffic = make_traffic(
+                self.config.testbed,
+                seed=req.env_seed,
+                constant_load=req.constant_load,
+            )
         return TenantEnvironment(
-            base.link,
+            TESTBEDS[self.config.testbed],
             traffic,
             shared,
             tenant_id,
-            noise_sigma=base.noise_sigma,
             seed=req.env_seed,
             turn_gate=None,  # the event loop itself is the serializer
             faults=self.config.faults,
@@ -251,14 +271,7 @@ class VectorizedFleetEngine:
         )
         # Service counters are cumulative across runs; report the delta.
         k_stats0 = knowledge.stats() if knowledge is not None else None
-        cap = cfg.max_concurrent or auto_concurrency(
-            self.db,
-            requests,
-            link,
-            testbed=cfg.testbed,
-            overcommit=cfg.overcommit,
-            use_pallas=cfg.use_pallas,
-        )
+        cap, routes = plan_admission(self.db, cfg, requests, link)
         recovery = cfg.recovery
 
         # Attempt-indexed state, laid out exactly like the threaded
@@ -281,11 +294,12 @@ class VectorizedFleetEngine:
         n_kills = 0
         n_recoveries = 0
         n_const_load = 0  # admissions on constant traffic, counted traced only
+        n_prerouted = 0  # admissions on the fleet's routing pass
 
         def build(now_s: float) -> int:
             """Admit the next queued request into its slot, up to the
             session's first step."""
-            nonlocal n_const_load
+            nonlocal n_const_load, n_prerouted
             i = pending.popleft()
             admit_time[i] = max(reqs[i].start_clock_s, now_s)
             state.admit_s[i] = admit_time[i]
@@ -297,8 +311,12 @@ class VectorizedFleetEngine:
                 budget = knowledge.probe_budget(
                     None, admit_time[i], cfg.max_samples
                 )
+            elif routes is not None and i < n:
+                cluster = self.db.clusters[routes[i]]
+                n_prerouted += 1
+                budget = cfg.max_samples
             else:
-                cluster = self._query_cluster(i, link, reqs[i].dataset)
+                cluster = self.db.query(request_features(link, reqs[i].dataset))
                 budget = cfg.max_samples
             env = self._make_tenant_env(reqs[i], i, shared)
             if busy is not None:
@@ -408,6 +426,7 @@ class VectorizedFleetEngine:
                 "events": self.events_processed - events0,
                 "admissions": busy.totals["admit"][1],
                 "const_load": n_const_load,
+                "prerouted": n_prerouted,
                 "reprobe_grants": limiter.grants,
                 "load_ns": 0,  # no shared load: every tenant's is constant
                 **{f"{k}_ns": ns for k, (ns, _) in busy.totals.items()},

@@ -35,6 +35,7 @@ import collections
 import contextlib
 import dataclasses
 import threading
+from typing import NamedTuple
 
 import numpy as np
 
@@ -278,6 +279,34 @@ def with_link_load(
 _OPT_CACHE: dict = {}
 
 
+def _route_and_score(
+    db: OfflineDB,
+    requests: list[FleetRequest],
+    testbed: str,
+    use_pallas: bool,
+) -> tuple[np.ndarray, np.ndarray]:
+    """The fleet's one routing and scoring pass: ``(demands, clusters)``,
+    request *i*'s predicted demand and its nearest cluster's index in
+    ``db.clusters`` (float64 ``ClusterModel.assign``, what ``db.query``
+    computes).  The whole of it is the trace span ``repro.fleet.score``."""
+    link = TESTBEDS[testbed]
+    demands = np.zeros(len(requests))
+    clusters = np.empty(len(requests), np.int64)
+    groups: dict[int, list[int]] = {}
+    with obs.span("fleet.score") as span:
+        for i, req in enumerate(requests):
+            k = db.cluster_model.assign(request_features(link, req.dataset))
+            clusters[i] = k
+            groups.setdefault(int(k), []).append(i)
+        for k, idxs in groups.items():
+            stack = db.clusters[k].surface_stack(db.bounds)
+            cand = stack.argmax_pts[None, :, :]  # one batch row per cluster
+            best, _ = stack.best_candidates(cand, use_pallas=use_pallas)
+            demands[idxs] = float(np.asarray(best)[0, stack.n_surfaces // 2])
+        span.set_metadata(clusters=len(groups))
+    return demands, clusters
+
+
 def predict_demands(
     db: OfflineDB,
     requests: list[FleetRequest],
@@ -296,20 +325,14 @@ def predict_demands(
     whole of it, routing and scoring up to the host's read of each result,
     is the trace span ``repro.fleet.score``.
     """
-    link = TESTBEDS[testbed]
-    demands = np.zeros(len(requests))
-    groups: dict[int, list[int]] = {}
-    with obs.span("fleet.score") as span:
-        for i, req in enumerate(requests):
-            k = db.cluster_model.assign(request_features(link, req.dataset))
-            groups.setdefault(int(k), []).append(i)
-        for k, idxs in groups.items():
-            stack = db.clusters[k].surface_stack(db.bounds)
-            cand = stack.argmax_pts[None, :, :]  # one batch row per cluster
-            best, _ = stack.best_candidates(cand, use_pallas=use_pallas)
-            demands[idxs] = float(np.asarray(best)[0, stack.n_surfaces // 2])
-        span.set_metadata(clusters=len(groups))
-    return demands
+    return _route_and_score(db, requests, testbed, use_pallas)[0]
+
+
+class AdmissionPlan(NamedTuple):
+    """The admission cap and the routing it was computed from."""
+
+    cap: int
+    clusters: np.ndarray  # int64: request i's cluster index in db.clusters
 
 
 def auto_concurrency(
@@ -320,15 +343,17 @@ def auto_concurrency(
     testbed: str = "xsede",
     overcommit: float = 2.0,
     use_pallas: bool = False,
-) -> int:
+) -> AdmissionPlan:
     """Admission cap from predicted demand: how many median-demand sessions
-    fit under the link's capacity times ``overcommit``."""
-    demands = predict_demands(db, requests, testbed=testbed, use_pallas=use_pallas)
+    fit under the link's capacity times ``overcommit``; with it each
+    request's cluster from the same pass, for engines whose knowledge is
+    frozen for the run to admit without routing again."""
+    demands, clusters = _route_and_score(db, requests, testbed, use_pallas)
     med = float(np.median(demands))
     if med <= 0.0:
-        return len(requests)
+        return AdmissionPlan(len(requests), clusters)
     cap = int(overcommit * link.bandwidth_mbps / med)
-    return max(1, min(cap, len(requests)))
+    return AdmissionPlan(max(1, min(cap, len(requests))), clusters)
 
 
 def single_tenant_optimum(
@@ -496,7 +521,7 @@ class FleetScheduler:
             testbed=self.config.testbed,
             overcommit=self.config.overcommit,
             use_pallas=self.use_pallas,
-        )
+        ).cap
 
     # ------------------------------------------------------------------ #
     def _make_tenant_env(

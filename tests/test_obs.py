@@ -126,6 +126,9 @@ def test_engine_counters_count_this_run_only(db, tmp_path):
     _traced(tmp_path, lambda: engine.run(_requests(8)))
     assert engine.counters["events"] == engine.events_processed - before
     assert engine.counters["admissions"] == 8
+    # frozen knowledge and a computed cap: every admission took the
+    # fleet's routing pass
+    assert engine.counters["prerouted"] == 8
 
 
 def test_admissions_count_requests_and_recoveries(db, tmp_path):
@@ -135,6 +138,7 @@ def test_admissions_count_requests_and_recoveries(db, tmp_path):
     assert report.recoveries >= 1  # the faults bit
     run = _one(events, "repro.fleet.run")[3]
     assert run["admissions"] == 8 + report.recoveries
+    assert run["prerouted"] == 0  # a fixed cap scores no demands
 
 
 def test_session_steps_hold_the_simulated_network(db, tmp_path):
