@@ -4,9 +4,12 @@
 (``repro.core.fleet.FleetScheduler``); ``engine="vectorized"`` is the
 event-loop engine that produces bit-identical ``FleetReport``s at parity
 scale and runs 1e5+ concurrent sessions (``repro.core.engine.vectorized``);
-``engine="sharded"`` partitions fleet slots across per-device event
-frontiers — bit-identical to the vectorized engine at parity scale,
-bulk-synchronous windows above it (``repro.core.engine.sharded``).
+``engine="sharded"`` runs the vectorized engine's loop at parity scale (the
+strict regime, bit-identical) and bulk-synchronous windows above it, with
+fleet slots spread over ``n_shards`` host-side event heaps
+(``repro.core.engine.sharded``).  Both loops share one per-run bookkeeping
+of admission, recovery and finish.  ``n_shards`` defaults to the host's
+device count; no shard runs on a device.
 """
 
 from repro.core.engine.api import (

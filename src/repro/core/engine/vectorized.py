@@ -29,6 +29,14 @@ O(log N) scheduling, and (above the parity regime) O(log N) contention
 bookkeeping via ``IndexedSharedLink``, which is what takes fleets from
 hundreds of sessions to 1e5+ (``benchmarks/fleet_scale.py``).
 
+Everything of a run but its loop is one per-run object, ``_FleetRun``:
+the attempt-indexed state, the arrival-ordered queue, where an admission's
+knowledge comes from (the knowledge service, the fleet's routing pass, or
+``db.query``), and where a finished session's goes, with recovery
+re-admission and the report's counts.  Two loops drive it: this engine's,
+one event at a time, and the windowed rounds of ``ShardedFleetEngine``,
+whose strict regime is this engine's loop itself.
+
 The batched-kernel path is unchanged: admission demand prediction still goes
 through ``SurfaceStack.best_candidates`` (vmapped gather or the Pallas
 kernel) via the shared module-level ``auto_concurrency``.  Where the
@@ -41,6 +49,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import functools
 import heapq
 
 import numpy as np
@@ -188,6 +197,217 @@ class _ActiveCounter:
         return self._active
 
 
+class _FleetRun:
+    """One fleet run's bookkeeping: everything of a run but its event loop.
+
+    Both loops drive one: the vectorized engine's, which pops one event at
+    a time, and the sharded engine's windowed rounds.  It holds the
+    attempt-indexed state, laid out exactly like the threaded scheduler's
+    (slots ``0..n-1`` are first attempts, recovery re-admissions append
+    further slots), the arrival-ordered queue, the active count and the
+    re-probe limiter, and where an admission's knowledge comes from and a
+    finished session's goes.  A loop calls :meth:`start`, then
+    :meth:`finish` at each finish event in ``(clock, slot)`` order, and
+    ends with :meth:`report_kwargs`.
+
+    ``heap`` takes each session's next event; ``make_env(request, slot)``
+    builds a tenant's environment.  ``busy`` (``obs.Busy`` or ``None``)
+    times admission and each environment's transfer and load.
+    ``active_view`` wraps the active counter for the limiter (the windowed
+    regime freezes it per window); by default the limiter reads it exactly.
+    """
+
+    def __init__(
+        self, db, cfg, requests, heap, make_env, *, busy=None, active_view=None
+    ):
+        self.db, self.cfg = db, cfg
+        self.requests = requests = with_link_load(requests, cfg.testbed)
+        n = len(requests)
+        self.link = link = TESTBEDS[cfg.testbed]
+        self.heap, self.make_env, self.busy = heap, make_env, busy
+        self.step = next if busy is None else busy.timed("step", next)
+        self.counter = counter = _ActiveCounter()
+        self.n_active = counter if active_view is None else active_view(counter)
+        # The limiter is consulted directly (no turn wrapper): gate events
+        # already arrive in simulated-time order.
+        self.limiter = ReprobeLimiter(cfg.reprobe_interval_s, n_active_fn=self.n_active)
+        knowledge = getattr(cfg, "knowledge", None)
+        if knowledge is not None and knowledge.db_for(None) is not db:
+            raise ValueError(
+                "knowledge service must serve the same OfflineDB the "
+                "engine runs against"
+            )
+        self.knowledge = knowledge
+        self.refresher = (
+            KnowledgeRefresher(db, link, cfg.refresh)
+            if cfg.refresh is not None and knowledge is None
+            else None
+        )
+        # Service counters are cumulative across runs; report the delta.
+        self._k_stats0 = knowledge.stats() if knowledge is not None else None
+        self.cap, self.routes = plan_admission(db, cfg, requests, link)
+
+        self.reqs: list[FleetRequest] = list(requests)
+        self.origin = list(range(n))
+        self.attempt_no = [0] * n
+        self.reports: list[TransferReport | None] = [None] * n
+        self.end_clock = [0.0] * n
+        self.admit_time = [0.0] * n
+        self.gens: list = [None] * n
+        self.envs: list[TenantEnvironment | None] = [None] * n
+        self.state = FleetStateArrays.allocate(n)
+        self.pending = collections.deque(
+            sorted(range(n), key=lambda i: (requests[i].start_clock_s, i))
+        )
+        self.n_kills = 0
+        self.n_recoveries = 0
+        self.n_const_load = 0  # admissions on constant traffic, traced only
+        self.n_prerouted = 0  # admissions on the fleet's routing pass
+        self.admit = self._build if busy is None else busy.timed("admit", self._build)
+
+    def start(self) -> None:
+        """The initial admission wave, before any event runs — mirrors the
+        threaded engine admitting (and clock-registering) the whole wave
+        before starting worker threads."""
+        for _ in range(min(self.cap, len(self.requests))):
+            self.admit_next(float("-inf"))
+
+    def _build(self, now_s: float) -> int:
+        """Admit the next queued request into its slot, up to the
+        session's first step."""
+        cfg, reqs, busy = self.cfg, self.reqs, self.busy
+        i = self.pending.popleft()
+        req = reqs[i]
+        admit_s = self.admit_time[i] = max(req.start_clock_s, now_s)
+        self.state.admit_s[i] = admit_s
+        # Knowledge snapshot resolved at admission, in event order — the
+        # same refresh-consistency point as the threaded engine.
+        if self.knowledge is not None:
+            feats = request_features(self.link, req.dataset)
+            cluster = self.knowledge.query_cluster(None, feats)
+            budget = self.knowledge.probe_budget(None, admit_s, cfg.max_samples)
+        elif self.routes is not None and i < len(self.routes):
+            cluster = self.db.clusters[self.routes[i]]
+            self.n_prerouted += 1
+            budget = cfg.max_samples
+        else:
+            cluster = self.db.query(request_features(self.link, req.dataset))
+            budget = cfg.max_samples
+        env = self.make_env(req, i)
+        if busy is not None:
+            env.transfer = busy.timed("netsim", env.transfer)
+            if getattr(env.traffic, "is_constant", False):
+                self.n_const_load += 1
+            else:
+                # the shared link load's reading, inside the transfer's; a
+                # constant load is left untimed, so the timer adds nothing
+                # to its fleets' netsim_ns
+                env.current_load = busy.timed("load", env.current_load)
+        env.clock_s = admit_s
+        self.envs[i] = env
+        self.counter.admit(admit_s)
+        sampler = AdaptiveSampler(
+            self.db,
+            z=cfg.z,
+            max_samples=budget,
+            bulk_chunks=cfg.bulk_chunks,
+            reprobe_gate=self.limiter,
+            recovery=cfg.recovery,
+        )
+        self.gens[i] = sampler.session(env, req.dataset, cluster)
+        return i
+
+    def admit_next(self, now_s: float) -> None:
+        """Admit the next queued request, if any, through its first step."""
+        if self.pending:
+            i = self.admit(now_s)
+            _advance(
+                i, self.gens, self.envs, self.reports, self.state, self.heap, self.step
+            )
+
+    def finish(self, i: int, now_s: float) -> None:
+        """Slot ``i``'s finish event at ``now_s``, in the same order as the
+        threaded worker's final serialized turn: fold knowledge in, re-admit
+        the killed session's residual, admit the next queued request, then
+        stop counting as active."""
+        self.end_clock[i] = now_s
+        state = self.state
+        state.end_s[i] = now_s
+        rep = self.reports[i]
+        if rep is not None:
+            if self.knowledge is not None:
+                # The service handles interrupted/collapsed sessions itself
+                # (fault signal, no fold-in).
+                self.knowledge.observe(
+                    rep, self.reqs[i].dataset, link=self.link, now_s=now_s
+                )
+            elif self.refresher is not None and not rep.interrupted:
+                self.refresher.observe(rep, self.reqs[i].dataset, now_s=now_s)
+            if rep.interrupted:
+                self._enqueue_recovery(i, rep, now_s)
+        self.admit_next(now_s)
+        self.counter.finish(now_s)
+        state.phase[i] = PHASE_IDLE
+        self.gens[i] = None
+        self.envs[i] = None  # free generator frame + env at scale
+
+    def _enqueue_recovery(self, i: int, rep: TransferReport, now_s: float):
+        self.n_kills += 1
+        recovery, req = self.cfg.recovery, self.reqs[i]
+        if (
+            recovery is None
+            or self.attempt_no[i] >= recovery.max_restarts
+            or rep.moved_mb >= req.dataset.total_mb - 1e-9
+        ):
+            return
+        self.n_recoveries += 1
+        nxt = dataclasses.replace(
+            req,
+            dataset=req.dataset.residual(rep.moved_mb),
+            start_clock_s=now_s + recovery.restart_delay_s,
+            env_seed=req.env_seed + 101,
+        )
+        j = len(self.reqs)
+        self.reqs.append(nxt)
+        self.origin.append(self.origin[i])
+        self.attempt_no.append(self.attempt_no[i] + 1)
+        self.reports.append(None)
+        self.end_clock.append(0.0)
+        self.admit_time.append(0.0)
+        self.gens.append(None)
+        self.envs.append(None)
+        self.state.grow_to(len(self.reqs))
+        self.pending.append(j)
+
+    def report_kwargs(self) -> dict:
+        """``assemble_fleet_report``'s keyword arguments for this run."""
+        if self.knowledge is not None:
+            now, then = self.knowledge.stats(), self._k_stats0
+            refreshes = now.refits - then.refits
+            entries = now.entries_folded - then.entries_folded
+        elif self.refresher is not None:
+            refreshes = self.refresher.refreshes
+            entries = self.refresher.entries_folded
+        else:
+            refreshes = entries = 0
+        return dict(
+            reqs=self.reqs,
+            origin=self.origin,
+            attempt_no=self.attempt_no,
+            reports=self.reports,
+            end_clock=self.end_clock,
+            admit_time=self.admit_time,
+            score_vs_single=self.cfg.score_vs_single,
+            reprobe_grants=self.limiter.grants,
+            reprobe_denials=self.limiter.denials,
+            admitted_concurrency=min(self.cap, len(self.requests)),
+            refreshes=refreshes,
+            refreshed_entries=entries,
+            kills=self.n_kills,
+            recoveries=self.n_recoveries,
+        )
+
+
 class VectorizedFleetEngine:
     """Run N concurrent sessions as one event loop, oracle-parity guaranteed.
 
@@ -206,12 +426,6 @@ class VectorizedFleetEngine:
         self.counters: dict[str, int] | None = None
 
     # ------------------------------------------------------------------ #
-    def _make_heap(self, n: int) -> VectorEventHeap:
-        """Event frontier for an N-slot fleet; the sharded engine overrides
-        this with a per-shard frontier merge (same push/pop contract, same
-        global ``(time, slot)`` order)."""
-        return VectorEventHeap(capacity=max(2 * n, 16))
-
     def _make_shared(self, link, n: int):
         mode = getattr(self.config, "contention", "auto")
         if mode == "exact" or (mode == "auto" and n <= AUTO_CONTENTION_CUTOVER):
@@ -219,7 +433,12 @@ class VectorizedFleetEngine:
         return IndexedSharedLink(link)
 
     def _make_tenant_env(
-        self, req: FleetRequest, tenant_id: int, shared
+        self,
+        req: FleetRequest,
+        tenant_id: int,
+        shared,
+        env_cls=TenantEnvironment,
+        **env_kw,
     ) -> TenantEnvironment:
         # the link, traffic, seed and noise ``make_testbed`` gives the
         # threaded oracle's tenants, with no environment built to read them
@@ -230,7 +449,7 @@ class VectorizedFleetEngine:
                 seed=req.env_seed,
                 constant_load=req.constant_load,
             )
-        return TenantEnvironment(
+        return env_cls(
             TESTBEDS[self.config.testbed],
             traffic,
             shared,
@@ -238,6 +457,7 @@ class VectorizedFleetEngine:
             seed=req.env_seed,
             turn_gate=None,  # the event loop itself is the serializer
             faults=self.config.faults,
+            **env_kw,
         )
 
     # ------------------------------------------------------------------ #
@@ -246,239 +466,63 @@ class VectorizedFleetEngine:
         n = len(requests)
         if n == 0:
             return FleetReport([], 0.0, 0.0, 0.0, 0.0, 0.0, 0, 0, 0)
-        requests = with_link_load(requests, cfg.testbed)
         # Busy-time counters only while a profiler session records; with
         # tracing off the loop calls the plain callables and reads no clock.
         busy = obs.Busy() if obs.active() else None
-        step = next if busy is None else busy.timed("step", next)
-        events0 = self.events_processed
-        link = TESTBEDS[cfg.testbed]
-        shared = self._make_shared(link, n)
-        counter = _ActiveCounter()
-        # The limiter is consulted directly (no turn wrapper): gate events
-        # already arrive in simulated-time order through the event heap.
-        limiter = ReprobeLimiter(cfg.reprobe_interval_s, n_active_fn=counter)
-        knowledge = getattr(cfg, "knowledge", None)
-        if knowledge is not None and knowledge.db_for(None) is not self.db:
-            raise ValueError(
-                "knowledge service must serve the same OfflineDB the "
-                "engine runs against"
-            )
-        refresher = (
-            KnowledgeRefresher(self.db, link, cfg.refresh)
-            if cfg.refresh is not None and knowledge is None
-            else None
-        )
-        # Service counters are cumulative across runs; report the delta.
-        k_stats0 = knowledge.stats() if knowledge is not None else None
-        cap, routes = plan_admission(self.db, cfg, requests, link)
-        recovery = cfg.recovery
-
-        # Attempt-indexed state, laid out exactly like the threaded
-        # scheduler's: slots 0..n-1 are first attempts, recovery
-        # re-admissions append further slots.
-        reqs: list[FleetRequest] = list(requests)
-        origin = list(range(n))
-        attempt_no = [0] * n
-        reports: list[TransferReport | None] = [None] * n
-        end_clock = [0.0] * n
-        admit_time = [0.0] * n
-        gens: list = [None] * n
-        envs: list[TenantEnvironment | None] = [None] * n
-        state = FleetStateArrays.allocate(n)
-        self.state = state
-        heap = self._make_heap(n)
-        pending = collections.deque(
-            sorted(range(n), key=lambda i: (reqs[i].start_clock_s, i))
-        )
-        n_kills = 0
-        n_recoveries = 0
-        n_const_load = 0  # admissions on constant traffic, counted traced only
-        n_prerouted = 0  # admissions on the fleet's routing pass
-
-        def build(now_s: float) -> int:
-            """Admit the next queued request into its slot, up to the
-            session's first step."""
-            nonlocal n_const_load, n_prerouted
-            i = pending.popleft()
-            admit_time[i] = max(reqs[i].start_clock_s, now_s)
-            state.admit_s[i] = admit_time[i]
-            # Knowledge snapshot resolved at admission, in event order —
-            # the same refresh-consistency point as the threaded engine.
-            if knowledge is not None:
-                feats = request_features(link, reqs[i].dataset)
-                cluster = knowledge.query_cluster(None, feats)
-                budget = knowledge.probe_budget(
-                    None, admit_time[i], cfg.max_samples
-                )
-            elif routes is not None and i < n:
-                cluster = self.db.clusters[routes[i]]
-                n_prerouted += 1
-                budget = cfg.max_samples
-            else:
-                cluster = self.db.query(request_features(link, reqs[i].dataset))
-                budget = cfg.max_samples
-            env = self._make_tenant_env(reqs[i], i, shared)
-            if busy is not None:
-                env.transfer = busy.timed("netsim", env.transfer)
-                if getattr(env.traffic, "is_constant", False):
-                    n_const_load += 1
-                else:
-                    # the shared link load's reading, inside the
-                    # transfer's; a constant load is left untimed, so the
-                    # timer adds nothing to its fleets' netsim_ns
-                    env.current_load = busy.timed("load", env.current_load)
-            env.clock_s = admit_time[i]
-            envs[i] = env
-            counter.admit(admit_time[i])
-            sampler = AdaptiveSampler(
-                self.db,
-                z=cfg.z,
-                max_samples=budget,
-                bulk_chunks=cfg.bulk_chunks,
-                reprobe_gate=limiter,
-                recovery=recovery,
-            )
-            gens[i] = sampler.session(env, reqs[i].dataset, cluster)
-            return i
-
-        admit = build if busy is None else busy.timed("admit", build)
-
-        def admit_next(now_s: float) -> None:
-            if pending:
-                self._advance(
-                    admit(now_s), gens, envs, reports, state, heap, step
-                )
-
-        def enqueue_recovery(i: int, now_s: float) -> None:
-            nonlocal n_kills, n_recoveries
-            rep = reports[i]
-            if rep is None or not rep.interrupted:
-                return
-            n_kills += 1
-            if (
-                recovery is None
-                or attempt_no[i] >= recovery.max_restarts
-                or rep.moved_mb >= reqs[i].dataset.total_mb - 1e-9
-            ):
-                return
-            n_recoveries += 1
-            nxt = dataclasses.replace(
-                reqs[i],
-                dataset=reqs[i].dataset.residual(rep.moved_mb),
-                start_clock_s=now_s + recovery.restart_delay_s,
-                env_seed=reqs[i].env_seed + 101,
-            )
-            j = len(reqs)
-            reqs.append(nxt)
-            origin.append(origin[i])
-            attempt_no.append(attempt_no[i] + 1)
-            reports.append(None)
-            end_clock.append(0.0)
-            admit_time.append(0.0)
-            gens.append(None)
-            envs.append(None)
-            state.grow_to(len(reqs))
-            pending.append(j)
-
-        # Initial admission wave, before any event runs — mirrors the
-        # threaded engine admitting (and clock-registering) the whole wave
-        # before starting worker threads.
-        for _ in range(min(cap, n)):
-            admit_next(float("-inf"))
+        shared = self._make_shared(TESTBEDS[cfg.testbed], n)
+        heap = VectorEventHeap(capacity=max(2 * n, 16))
+        make_env = functools.partial(self._make_tenant_env, shared=shared)
+        run = _FleetRun(self.db, cfg, requests, heap, make_env, busy=busy)
+        self.state = state = run.state
+        run.start()
 
         # ---------------- the event loop ---------------- #
+        pop, advance, finish, step = heap.pop, _advance, run.finish, run.step
+        gens, envs, reports = run.gens, run.envs, run.reports
+        events = 0
         while len(heap):
-            _, i = heap.pop()
-            self.events_processed += 1
+            _, i = pop()
+            events += 1
             if state.phase[i] == PHASE_FINISH:
-                env = envs[i]
-                now = env.clock_s
-                end_clock[i] = now
-                state.end_s[i] = now
-                rep = reports[i]
-                # Same per-finish order as the threaded worker's final
-                # serialized turn: fold knowledge in, re-admit the killed
-                # session's residual, admit the next queued request, then
-                # stop counting as active.
-                if knowledge is not None and rep is not None:
-                    # The service handles interrupted/collapsed sessions
-                    # itself (fault signal, no fold-in).
-                    knowledge.observe(rep, reqs[i].dataset, link=link, now_s=now)
-                elif (
-                    refresher is not None
-                    and rep is not None
-                    and not rep.interrupted
-                ):
-                    refresher.observe(rep, reqs[i].dataset, now_s=now)
-                enqueue_recovery(i, now)
-                admit_next(now)
-                counter.finish(now)
-                state.phase[i] = PHASE_IDLE
-                gens[i] = None
-                envs[i] = None  # free generator frame + env at scale
+                finish(i, envs[i].clock_s)
                 continue
-            self._advance(i, gens, envs, reports, state, heap, step)
+            advance(i, gens, envs, reports, state, heap, step)
+        self.events_processed += events
 
         self.counters = None
         if busy is not None:
             self.counters = {
-                "events": self.events_processed - events0,
+                "events": events,
                 "admissions": busy.totals["admit"][1],
-                "const_load": n_const_load,
-                "prerouted": n_prerouted,
-                "reprobe_grants": limiter.grants,
+                "const_load": run.n_const_load,
+                "prerouted": run.n_prerouted,
+                "reprobe_grants": run.limiter.grants,
                 "load_ns": 0,  # no shared load: every tenant's is constant
                 **{f"{k}_ns": ns for k, (ns, _) in busy.totals.items()},
             }
         return assemble_fleet_report(
-            self.db,
-            cfg.testbed,
-            requests,
-            reqs=reqs,
-            origin=origin,
-            attempt_no=attempt_no,
-            reports=reports,
-            end_clock=end_clock,
-            admit_time=admit_time,
-            score_vs_single=cfg.score_vs_single,
-            reprobe_grants=limiter.grants,
-            reprobe_denials=limiter.denials,
-            admitted_concurrency=min(cap, n),
-            refreshes=(
-                knowledge.stats().refits - k_stats0.refits
-                if knowledge is not None
-                else (refresher.refreshes if refresher is not None else 0)
-            ),
-            refreshed_entries=(
-                knowledge.stats().entries_folded - k_stats0.entries_folded
-                if knowledge is not None
-                else (refresher.entries_folded if refresher is not None else 0)
-            ),
-            kills=n_kills,
-            recoveries=n_recoveries,
+            self.db, cfg.testbed, run.requests, **run.report_kwargs()
         )
 
-    # ------------------------------------------------------------------ #
-    @staticmethod
-    def _advance(i, gens, envs, reports, state, heap, step=next) -> None:
-        """Resume slot ``i``'s generator through exactly one interaction.
 
-        The generator performs the environment interaction it announced with
-        its previous yield, then either announces the next one (re-queue at
-        its new clock) or returns its ``TransferReport`` (queue the finish
-        event at the session's final clock — the same key as the threaded
-        worker's final turn).  ``step`` is ``next``, or ``next`` timed.
-        """
-        try:
-            t, phase, prm = step(gens[i])
-        except StopIteration as stop:
-            reports[i] = stop.value
-            state.phase[i] = PHASE_FINISH
-            state.next_event_s[i] = envs[i].clock_s
-            heap.push(envs[i].clock_s, i)
-            return
-        state.phase[i] = phase
-        state.params[i] = prm.as_tuple()
-        state.next_event_s[i] = t
-        heap.push(t, i)
+def _advance(i, gens, envs, reports, state, heap, step=next) -> None:
+    """Resume slot ``i``'s generator through exactly one interaction.
+
+    The generator performs the environment interaction it announced with
+    its previous yield, then either announces the next one (re-queue at its
+    new clock) or returns its ``TransferReport`` (queue the finish event at
+    the session's final clock — the same key as the threaded worker's final
+    turn).  ``step`` is ``next``, or ``next`` timed.
+    """
+    try:
+        t, phase, prm = step(gens[i])
+    except StopIteration as stop:
+        reports[i] = stop.value
+        state.phase[i] = PHASE_FINISH
+        state.next_event_s[i] = envs[i].clock_s
+        heap.push(envs[i].clock_s, i)
+        return
+    state.phase[i] = phase
+    state.params[i] = prm.as_tuple()
+    state.next_event_s[i] = t
+    heap.push(t, i)

@@ -4,7 +4,9 @@ across fleet sizes, the windowed scale regime must be deterministic and
 physically close to strict, and the frontier / partition / config plumbing
 gets direct unit coverage."""
 
+import json
 import math
+import pathlib
 
 import numpy as np
 import pytest
@@ -12,8 +14,10 @@ import pytest
 from repro.core import (
     EngineConfig,
     FleetRequest,
+    KnowledgeService,
     RecoveryConfig,
     RefreshConfig,
+    ServiceConfig,
     run_fleet,
 )
 from repro.core.engine import (
@@ -35,6 +39,7 @@ from repro.testing import (
 )
 
 START = 4 * 3600.0
+GOLDEN = pathlib.Path(__file__).parent / "golden"
 
 
 @pytest.fixture(scope="module")
@@ -242,6 +247,63 @@ def test_windowed_engine_actually_windows(dbs):
     fleet = eng.run(reqs)
     assert len(fleet.reports) == 64
     assert eng.windows_run >= 2  # the run really crossed window barriers
+
+
+# ------------------------------------------------------------------ #
+# windowed regime: exact fleets pinned by recorded traces
+# ------------------------------------------------------------------ #
+WINDOWED_GOLDEN_CASES = ("query", "routed", "refresh", "service")
+
+
+def _golden_windowed_fleet(dbs, case):
+    """A 24-request windowed fleet under the kill schedule of
+    ``test_windowed_faulted_run_recovers_deterministically``, recovery on.
+
+    ``case`` picks where admission finds its knowledge: frozen at a fixed
+    cap (every admission queries the DB), frozen at the auto cap (first
+    attempts take the fleet's routing pass, recoveries query), a
+    ``RefreshConfig``, or a ``KnowledgeService``.  The last two fold
+    finished sessions into the DB, so each gets a freshly fitted one.
+    """
+    faults = FaultSchedule.generate(
+        23,
+        start_s=START,
+        horizon_s=90.0,
+        n_flaps=0,
+        n_drops=0,
+        n_bursts=0,
+        n_kills=4,
+        n_tenants=8,
+    )
+    db = dbs["xsede"]
+    kw = dict(max_concurrent=None if case == "routed" else 8)
+    if case == "refresh":
+        db = build_scenario_db("xsede")
+        kw["refresh"] = RefreshConfig(every_completions=2, min_entries=4)
+    elif case == "service":
+        db = build_scenario_db("xsede")
+        kw["knowledge"] = KnowledgeService(
+            db, ServiceConfig(max_staleness_s=120.0, drift_threshold=0.1)
+        )
+    return run_fleet(
+        db,
+        _scale_requests(24),
+        EngineConfig(
+            engine="sharded", n_shards=4, shard_window_s=120.0,
+            score_vs_single=False, faults=faults, recovery=RecoveryConfig(),
+            **kw,
+        ),
+    )
+
+
+@pytest.mark.parametrize("case", WINDOWED_GOLDEN_CASES)
+def test_windowed_fleet_matches_recorded_trace(dbs, case):
+    # the windowed regime's own fleet, not only its determinism: the
+    # canonical trace equals the one recorded in tests/golden/
+    trace = canonical_trace(_golden_windowed_fleet(dbs, case))
+    want = json.loads((GOLDEN / f"windowed-{case}.json").read_text())
+    assert json.loads(json.dumps(trace)) == want
+    assert trace[1] >= 1  # a kill landed: recovery re-admission ran
 
 
 # ------------------------------------------------------------------ #
