@@ -263,6 +263,8 @@ class _FleetRun:
         self.n_recoveries = 0
         self.n_const_load = 0  # admissions on constant traffic, traced only
         self.n_prerouted = 0  # admissions on the fleet's routing pass
+        self.n_law_terms = 0  # finished environments' law terms, traced only
+        self.n_noise_blocks = 0  # and their noise blocks, traced only
         self.admit = self._build if busy is None else busy.timed("admit", self._build)
 
     def start(self) -> None:
@@ -330,6 +332,10 @@ class _FleetRun:
         threaded worker's final serialized turn: fold knowledge in, re-admit
         the killed session's residual, admit the next queued request, then
         stop counting as active."""
+        if self.busy is not None:
+            env = self.envs[i]
+            self.n_law_terms += env.n_law_terms
+            self.n_noise_blocks += env.n_noise_blocks
         self.end_clock[i] = now_s
         state = self.state
         state.end_s[i] = now_s
@@ -496,6 +502,8 @@ class VectorizedFleetEngine:
                 "admissions": busy.totals["admit"][1],
                 "const_load": run.n_const_load,
                 "prerouted": run.n_prerouted,
+                "law_terms": run.n_law_terms,
+                "noise_blocks": run.n_noise_blocks,
                 "reprobe_grants": run.limiter.grants,
                 "load_ns": 0,  # no shared load: every tenant's is constant
                 **{f"{k}_ns": ns for k, (ns, _) in busy.totals.items()},

@@ -93,6 +93,55 @@ class LinkSpec:
                                    # never reaches buffer/RTT)
 
 
+#: Terms of the throughput law an environment keeps for its static link,
+#: one entry per ``(cc, p, pp, avg_file_mb, n_files)``; the oldest is
+#: evicted first, as in ``ThroughputSurface``'s predict cache.
+LAW_TERMS_CAP = 4096
+#: Noise draws per refill of an environment's block.  ``Generator.normal``
+#: with ``size=k`` gives the values of k scalar calls in order, so the size
+#: changes no value, only how often the generator is called; draws left
+#: when an environment is let go are never observed.
+NOISE_BLOCK = 16
+
+
+def _law_terms(params: TransferParams, avg_file_mb: float, n_files: int,
+               link: LinkSpec) -> tuple:
+    """The throughput law's terms that depend on the link, the parameters
+    and the dataset alone, not on the load or the contenders:
+    ``(bandwidth, fair-share floor, stream supply, stream demand,
+    congestion knee, congestion slope, file megabits, per-file control
+    overhead, disk bound)``, each computed as ``mean_throughput`` always
+    has, so the law's value is the same bit for bit."""
+    cc, p, pp = params.cc, params.p, params.pp
+    streams = cc * p
+
+    # Per-stream steady-state TCP rate: the lesser of the window limit
+    # (buffer/RTT) and the Mathis loss-rate cap, expressed as the number of
+    # streams a lossy path needs to fill the pipe.
+    window_cap = (link.tcp_buffer_mb * 8.0) / max(link.rtt_s, 1e-6)
+    loss_cap = link.bandwidth_mbps / link.streams_to_saturate
+    per_stream = min(window_cap, loss_cap)
+
+    # Server-process scheduling gain: a single GridFTP process cannot keep
+    # all its streams busy; more processes push harder (the paper's
+    # cc=8,p=2 > cc=4,p=4 example), saturating near 1.3x and degrading
+    # once cc exceeds the end-system cores.
+    cpu_factor = min((cc / (cc + 1.5)) * 1.55, 1.30)
+    if cc > link.cores:
+        cpu_factor /= 1.0 + 0.25 * (cc - link.cores)
+    demand = streams * per_stream
+
+    # Per-file control-channel overhead, amortized by pipelining: each file
+    # costs one control RTT unless pipelined; cc processes hide it further.
+    eff_pp = min(pp, max(n_files // max(cc, 1), 1))
+    overhead = link.rtt_s / (eff_pp * max(1.0, 0.8 * cc))
+
+    return (link.bandwidth_mbps, 0.05 * link.bandwidth_mbps,
+            demand * cpu_factor, demand, link.congestion_knee,
+            0.12 * link.loss_sensitivity, avg_file_mb * 8.0, overhead,
+            min(link.disk_read_mbps, link.disk_write_mbps))
+
+
 class Environment:
     """A simulated end-to-end transfer path with background traffic.
 
@@ -113,6 +162,10 @@ class Environment:
         self._live_params: tuple[int, int, int] | None = None  # open sessions
         self.faults = faults            # netsim.faults.FaultSchedule | None
         self.tenant_id: int | None = None  # set by TenantEnvironment
+        self._law_memo: dict[tuple, tuple] = {}  # static link's law terms
+        self._noise_left: list[float] = []  # this block's draws, reversed
+        self.n_law_terms = 0     # law terms computed for the static link
+        self.n_noise_blocks = 0  # noise blocks drawn
 
     # ------------------------------------------------------------------ #
     # ground-truth throughput law
@@ -127,57 +180,65 @@ class Environment:
         ``link`` overrides the environment's static LinkSpec — the fault
         path evaluates the law under a fault-perturbed spec per segment;
         every fault-free caller leaves it ``None``.
+
+        The law's load-free terms (:func:`_law_terms`) are kept per
+        ``(cc, p, pp, avg_file_mb, n_files)`` for the environment's own
+        link, so a session re-transferring one dataset with one parameter
+        choice evaluates only the load- and contention-dependent part; an
+        overriding ``link`` computes its terms afresh and keeps nothing.
         """
         if link is None:
-            link = self.link
-        cc, p, pp = params.cc, params.p, params.pp
-        streams = cc * p
-
-        # Per-stream steady-state TCP rate: the lesser of the window limit
-        # (buffer/RTT) and the Mathis loss-rate cap, expressed as the number of
-        # streams a lossy path needs to fill the pipe.
-        window_cap = (link.tcp_buffer_mb * 8.0) / max(link.rtt_s, 1e-6)
-        loss_cap = link.bandwidth_mbps / link.streams_to_saturate
-        per_stream = min(window_cap, loss_cap)
+            key = (params.cc, params.p, params.pp, avg_file_mb, n_files)
+            terms = self._law_memo.get(key)
+            if terms is None:
+                terms = self._keep_law_terms(key, params, avg_file_mb, n_files)
+        else:
+            terms = _law_terms(params, avg_file_mb, n_files, link)
+        (bandwidth, floor, supply, demand, knee, slope, file_mbit, overhead,
+         disk) = terms
 
         # Available capacity after diurnal external load and logged contenders.
         # TCP fair share puts a floor under the subtraction: with k active
         # contending flows, this flow still gets ~1/(k+1) of the post-load
         # capacity no matter how aggressively the others are pushing.
-        post_load = link.bandwidth_mbps * (1.0 - ext_load)
+        post_load = bandwidth * (1.0 - ext_load)
         avail = post_load - contending_mbps
         if n_contending > 0:
             avail = max(avail, post_load / (1.0 + n_contending))
-        avail = max(avail, 0.05 * link.bandwidth_mbps)
+        avail = max(avail, floor)
 
-        # Server-process scheduling gain: a single GridFTP process cannot keep
-        # all its streams busy; more processes push harder (the paper's
-        # cc=8,p=2 > cc=4,p=4 example), saturating near 1.3x and degrading
-        # once cc exceeds the end-system cores.
-        cpu_factor = min((cc / (cc + 1.5)) * 1.55, 1.30)
-        if cc > link.cores:
-            cpu_factor /= 1.0 + 0.25 * (cc - link.cores)
-
-        agg = min(streams * per_stream * cpu_factor, avail)
+        agg = min(supply, avail)
 
         # Congestion: stream demand past the knee causes loss + queueing
         # delay (raw window demand, regardless of how well the server feeds
         # it).  Smooth, gentle decline so the surface has an interior maximum.
-        over = (streams * per_stream) / max(avail * link.congestion_knee, 1e-6)
+        over = demand / max(avail * knee, 1e-6)
         if over > 1.0:
-            agg /= 1.0 + 0.12 * link.loss_sensitivity * (over - 1.0)
+            agg /= 1.0 + slope * (over - 1.0)
 
-        # Per-file control-channel overhead, amortized by pipelining: each file
-        # costs one control RTT unless pipelined; cc processes hide it further.
+        # Per-file control-channel overhead, amortized by pipelining.
         rate = max(agg, 1e-3)
-        xfer_time = (avg_file_mb * 8.0) / rate          # seconds per file
-        eff_pp = min(pp, max(n_files // max(cc, 1), 1))
-        overhead = link.rtt_s / (eff_pp * max(1.0, 0.8 * cc))
+        xfer_time = file_mbit / rate                    # seconds per file
         efficiency = xfer_time / (xfer_time + overhead)
         agg *= efficiency
 
         # Storage bounds (Assumption 3).
-        return float(min(agg, link.disk_read_mbps, link.disk_write_mbps))
+        return float(min(agg, disk))
+
+    def _keep_law_terms(self, key: tuple, params: TransferParams,
+                        avg_file_mb: float, n_files: int) -> tuple:
+        """The static link's law terms for ``key``, computed and kept.
+
+        Bounded like ``ThroughputSurface``'s predict cache: ``optimal`` and
+        history replay walk many keys on one environment, so past
+        ``LAW_TERMS_CAP`` the oldest key is evicted first."""
+        terms = _law_terms(params, avg_file_mb, n_files, self.link)
+        memo = self._law_memo
+        if len(memo) >= LAW_TERMS_CAP:
+            memo.pop(next(iter(memo)), None)
+        memo[key] = terms
+        self.n_law_terms += 1
+        return terms
 
     def optimal(self, bounds: ParamBounds, avg_file_mb: float, n_files: int,
                 ext_load: float | None = None) -> tuple[TransferParams, float]:
@@ -201,6 +262,18 @@ class Environment:
 
     def advance(self, seconds: float) -> None:
         self.clock_s += float(seconds)
+
+    def _noise(self) -> float:
+        """The next draw of this environment's measurement noise, the value
+        ``float(self._rng.normal(0.0, self.noise_sigma))`` would give: the
+        only reader of ``self._rng``, refilled ``NOISE_BLOCK`` at a time."""
+        left = self._noise_left
+        if not left:
+            left.extend(self._rng.normal(0.0, self.noise_sigma,
+                                         NOISE_BLOCK).tolist())
+            left.reverse()
+            self.n_noise_blocks += 1
+        return left.pop()
 
     def _setup_cost_s(self, params: TransferParams) -> float:
         """Process spawn + TCP slow-start ramp charged on a parameter
@@ -252,7 +325,7 @@ class Environment:
         mean = self.mean_throughput(params, avg_file_mb, n_files, load,
                                     contending_mbps=contending,
                                     n_contending=n_active)
-        noisy = mean * float(1.0 + self._rng.normal(0.0, self.noise_sigma))
+        noisy = mean * (1.0 + self._noise())
         noisy = max(noisy, 0.01 * mean)
 
         # Setup cost: process spawn + slow-start ramp, only on param change.
@@ -290,7 +363,7 @@ class Environment:
         faults = self.faults
         load = self.current_load()
         contending, n_active = self._contention()
-        noise = float(self._rng.normal(0.0, self.noise_sigma))
+        noise = self._noise()
         setup_s = self._setup_cost_s(params)
         t0 = self.clock_s
         kill_at = faults.next_kill(self.tenant_id, t0)
@@ -341,8 +414,7 @@ class Environment:
         """Steady-state noisy rate (no setup charge) — used for log replay."""
         load = self.current_load()
         mean = self.mean_throughput(params, avg_file_mb, n_files, load)
-        return float(max(mean * (1.0 + self._rng.normal(0.0, self.noise_sigma)),
-                         0.01 * mean))
+        return float(max(mean * (1.0 + self._noise()), 0.01 * mean))
 
 
 # ----------------------------------------------------------------------- #

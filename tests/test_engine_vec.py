@@ -4,6 +4,11 @@ admission — plus the admission path (one routing pass per fleet, one
 environment per admission) and unit coverage of the engine's state arrays
 and the incremental active-session counter."""
 
+import hashlib
+import json
+import pathlib
+import sys
+
 import numpy as np
 import pytest
 
@@ -307,6 +312,69 @@ def test_indexed_contention_close_to_exact(dbs):
     for a, b in zip(exact.reports, indexed.reports):
         assert b.achieved_mbps == pytest.approx(a.achieved_mbps, rel=1e-6)
     assert indexed.goodput_mbps == pytest.approx(exact.goodput_mbps, rel=1e-6)
+
+
+# ------------------------------------------------------------------ #
+# the benchmark cells' traffic: reports pinned by recorded hashes
+# ------------------------------------------------------------------ #
+#: md5 of ``repr(report)`` of a 200-request vectorized fleet of each
+#: cell's traffic, ``(traffic, seed, contention)``, recorded with the law
+#: computed whole on every chunk and one scalar noise draw per chunk.
+CELL_FLEET_MD5 = {
+    ("fleet-2k", 11, "exact"): "1692c20d656461f2a5d8294b49e0eab6",
+    ("fleet-2k", 22, "exact"): "cf76346046758128ab28aeac1b673e65",
+    ("fleet-2k", 33, "exact"): "24c11c8f9e65dd80523dac73e3a30351",
+    ("poisson-2k", 11, "exact"): "acb47300f336bb02b386f0b472ad6934",
+    ("poisson-2k", 22, "exact"): "cb25b3329d258469453f38586c62b6da",
+    ("poisson-2k", 33, "exact"): "682e93407ef67233eba8bd88e2d095f3",
+    ("fleet-2k", 11, "indexed"): "abeeeab202a5e0770d6383749f919bba",
+    ("fleet-2k", 22, "indexed"): "7d96152012bcc672c0fcf2787d171dc3",
+    ("fleet-2k", 33, "indexed"): "24a351b96b0169af5b60e4e908eb8480",
+    ("poisson-2k", 11, "indexed"): "738ebb8d61a0d7c78ed525e195164b6b",
+    ("poisson-2k", 22, "indexed"): "1f4e9b43f5d240fdeab399b894d4cbc8",
+    ("poisson-2k", 33, "indexed"): "47779ab10a0ccc0dd1074e2b1f026cd0",
+}
+#: each traffic's configuration in ``benchmarks/chip/configs``
+CELL_CONFIG = {"fleet-2k": "xsede-fleet", "poisson-2k": "didclab-lan"}
+
+
+@pytest.fixture(scope="module")
+def cell_fleet():
+    """``(traffic, seed, contention) -> (db, requests, config)``: a
+    200-request fleet drawn by the benchmark's fleet driver from the
+    cell's configuration and traffic, on a scenario DB of its testbed."""
+    root = pathlib.Path(__file__).resolve().parents[1]
+    if str(root) not in sys.path:
+        sys.path.insert(0, str(root))
+    from benchmarks.chip.drivers import fleet
+
+    chip = root / "benchmarks" / "chip"
+    built = {}
+
+    def make(traffic, seed, contention):
+        config = json.loads(
+            (chip / "configs" / f"{CELL_CONFIG[traffic]}.json").read_text()
+        )
+        mix = json.loads((chip / "traffic" / f"{traffic}.json").read_text())
+        testbed = config["testbed"]
+        if testbed not in built:
+            built[testbed] = build_scenario_db(testbed)
+        return (
+            built[testbed],
+            fleet.requests(config, dict(mix, sessions=200), seed),
+            fleet.engine_config(config, contention=contention),
+        )
+
+    return make
+
+
+@pytest.mark.parametrize("key", sorted(CELL_FLEET_MD5), ids=lambda k: "-".join(map(str, k)))
+def test_cell_traffic_reports_match_recorded_hashes(cell_fleet, key):
+    traffic, seed, contention = key
+    report = run_fleet(*cell_fleet(traffic, seed, contention))
+    assert len(report.sessions) == 200
+    digest = hashlib.md5(repr(report).encode()).hexdigest()
+    assert digest == CELL_FLEET_MD5[key]
 
 
 # ------------------------------------------------------------------ #

@@ -89,14 +89,20 @@ def test_generate_is_deterministic_per_seed():
 # environment composition
 # ------------------------------------------------------------------ #
 def test_empty_schedule_matches_fault_free_path():
+    from repro.netsim.environment import NOISE_BLOCK
+
     a = make_testbed("xsede", seed=1, constant_load=0.1)
     b = make_testbed("xsede", seed=1, constant_load=0.1)
     b.faults = FaultSchedule(())
-    ra = a.transfer(PRM, 800.0, 100.0, 50)
-    rb = b.transfer(PRM, 800.0, 100.0, 50)
-    assert rb.effective_mbps == pytest.approx(ra.effective_mbps, rel=1e-12)
-    assert rb.steady_mbps == pytest.approx(ra.steady_mbps, rel=1e-12)
-    assert rb.elapsed_s == pytest.approx(ra.elapsed_s, rel=1e-12)
+    # chunk after chunk, across noise blocks and parameter changes
+    for k in range(2 * NOISE_BLOCK + 8):
+        prm = PRM if k % 7 < 4 else TransferParams(8, 2, 4)
+        ra = a.transfer(prm, 800.0, 100.0, 50)
+        rb = b.transfer(prm, 800.0, 100.0, 50)
+        assert rb.effective_mbps == pytest.approx(ra.effective_mbps, rel=1e-12)
+        assert rb.steady_mbps == pytest.approx(ra.steady_mbps, rel=1e-12)
+        assert rb.elapsed_s == pytest.approx(ra.elapsed_s, rel=1e-12)
+    assert a.n_noise_blocks == b.n_noise_blocks == 3
 
 
 def test_faults_none_is_untouched_fast_path():

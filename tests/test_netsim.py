@@ -8,9 +8,10 @@ from _hypothesis_compat import given, settings, st
 
 from repro.netsim import (
     make_testbed, make_dataset, ParamBounds, TransferParams, DiurnalTraffic,
-    generate_history,
+    generate_history, CapacityDrop, FaultSchedule, IndexedSharedLink,
+    LinkFlap, LossBurst, TenantEnvironment,
 )
-from repro.netsim.testbeds import _TRAFFIC, make_traffic
+from repro.netsim.testbeds import _TRAFFIC, TESTBEDS, make_traffic
 
 B = ParamBounds()
 
@@ -206,3 +207,128 @@ def test_regime_shift_traffic_deterministic_step():
                                  ripple=0.1)
     for t in (0.0, 250.0, 900.0, 1100.0, 3600.0):
         assert 0.0 <= rippled.load_at(t) <= 0.95
+
+
+# ------------------------------------------------------------------ #
+# the law's kept terms and the noise blocks change no value
+# ------------------------------------------------------------------ #
+def _plain_law(link, params, avg_file_mb, n_files, ext_load,
+               contending_mbps=0.0, n_contending=0):
+    """The throughput law with every term computed on every call: the
+    reference ``Environment.mean_throughput``'s kept terms are held to."""
+    cc, p, pp = params.cc, params.p, params.pp
+    streams = cc * p
+    window_cap = (link.tcp_buffer_mb * 8.0) / max(link.rtt_s, 1e-6)
+    loss_cap = link.bandwidth_mbps / link.streams_to_saturate
+    per_stream = min(window_cap, loss_cap)
+    post_load = link.bandwidth_mbps * (1.0 - ext_load)
+    avail = post_load - contending_mbps
+    if n_contending > 0:
+        avail = max(avail, post_load / (1.0 + n_contending))
+    avail = max(avail, 0.05 * link.bandwidth_mbps)
+    cpu_factor = min((cc / (cc + 1.5)) * 1.55, 1.30)
+    if cc > link.cores:
+        cpu_factor /= 1.0 + 0.25 * (cc - link.cores)
+    agg = min(streams * per_stream * cpu_factor, avail)
+    over = (streams * per_stream) / max(avail * link.congestion_knee, 1e-6)
+    if over > 1.0:
+        agg /= 1.0 + 0.12 * link.loss_sensitivity * (over - 1.0)
+    rate = max(agg, 1e-3)
+    xfer_time = (avg_file_mb * 8.0) / rate
+    eff_pp = min(pp, max(n_files // max(cc, 1), 1))
+    overhead = link.rtt_s / (eff_pp * max(1.0, 0.8 * cc))
+    agg *= xfer_time / (xfer_time + overhead)
+    return float(min(agg, link.disk_read_mbps, link.disk_write_mbps))
+
+
+_DATASETS = [make_dataset(size, 11) for size in ("small", "medium", "large")]
+
+
+@pytest.mark.parametrize("contenders", [(0.0, 0), (2_400.0, 3)],
+                         ids=["alone", "contended"])
+@pytest.mark.parametrize("load", [0.0, 0.15, 0.6, 0.95])
+@pytest.mark.parametrize("testbed", ["xsede", "didclab"])
+def test_kept_law_terms_equal_the_plain_law_on_the_lattice(testbed, load,
+                                                           contenders):
+    from repro.netsim.environment import LAW_TERMS_CAP
+
+    env = make_testbed(testbed, seed=0)
+    contending, n_active = contenders
+    lattice = B.grid()
+    for ds in _DATASETS:
+        want = [_plain_law(env.link, prm, ds.avg_file_mb, ds.n_files, load,
+                           contending, n_active) for prm in lattice]
+        for _ in range(2):  # the first pass keeps the terms, the second reads
+            got = [env.mean_throughput(prm, ds.avg_file_mb, ds.n_files, load,
+                                       contending_mbps=contending,
+                                       n_contending=n_active)
+                   for prm in lattice]
+            assert got == want
+    # three datasets' lattices overflow the bound: the oldest keys went
+    assert env.n_law_terms == len(_DATASETS) * len(lattice)
+    assert len(env._law_memo) == LAW_TERMS_CAP
+
+
+@pytest.mark.parametrize("fault", [
+    CapacityDrop(0.0, 100.0, factor=0.3),
+    LinkFlap(0.0, 100.0),
+    LossBurst(0.0, 100.0),
+], ids=["capacity-drop", "link-flap", "loss-burst"])
+def test_an_overriding_link_gets_its_own_law(fault):
+    env = make_testbed("xsede", seed=0)
+    ds = _DATASETS[1]
+    args = (TransferParams(8, 4, 4), ds.avg_file_mb, ds.n_files, 0.15)
+    kept = env.mean_throughput(*args, contending_mbps=900.0, n_contending=2)
+    # the spec the fault path hands the law inside the fault
+    other = FaultSchedule((fault,)).link_at(env.link, 50.0)
+    got = env.mean_throughput(*args, contending_mbps=900.0, n_contending=2,
+                              link=other)
+    assert got == _plain_law(other, *args, 900.0, 2)
+    assert got != kept
+    # the override kept nothing; the static link's value is as it was
+    assert env.n_law_terms == 1 and len(env._law_memo) == 1
+    assert env.mean_throughput(*args, contending_mbps=900.0,
+                               n_contending=2) == kept
+
+
+class _ScalarNoise(TenantEnvironment):
+    """A tenant that draws its noise one scalar per chunk from its own
+    generator: the reference the noise blocks are held to."""
+
+    def __init__(self, *args, seed, **kwargs):
+        super().__init__(*args, seed=seed, **kwargs)
+        self._scalar_rng = np.random.default_rng(seed)
+
+    def _noise(self):
+        return float(self._scalar_rng.normal(0.0, self.noise_sigma))
+
+
+def _tenant(cls, testbed, seed):
+    link = TESTBEDS[testbed]
+    return cls(link, make_traffic(testbed, seed=seed), IndexedSharedLink(link),
+               0, seed=seed)
+
+
+def _session(env, n=45):
+    """Chunks and steady-state readings interleaved, with parameter changes:
+    ``n`` noise draws in all."""
+    out = []
+    for k in range(n):
+        prm = TransferParams(2 + k // 9, 4, 1 + k % 3 // 2 * 7)
+        ds = _DATASETS[k % 3]
+        if k % 4 == 3:
+            out.append(env.measure_steady(prm, ds.avg_file_mb, ds.n_files))
+        else:
+            out.append(env.transfer(prm, 400.0, ds.avg_file_mb, ds.n_files,
+                                    is_sample=k % 5 == 0))
+    return out, env.clock_s, env.sample_count
+
+
+@pytest.mark.parametrize("seed", [0, 7, 2**31 + 5])
+@pytest.mark.parametrize("testbed", ["xsede", "didclab"])
+def test_noise_blocks_give_the_scalar_draws(testbed, seed):
+    from repro.netsim.environment import NOISE_BLOCK
+
+    env = _tenant(TenantEnvironment, testbed, seed)
+    assert _session(env) == _session(_tenant(_ScalarNoise, testbed, seed))
+    assert env.n_noise_blocks == -(-45 // NOISE_BLOCK) >= 3

@@ -175,6 +175,18 @@ def test_const_load_counts_admissions_on_constant_traffic(db, tmp_path, load):
     assert run["const_load"] == (8 if load is not None else 0)
 
 
+@pytest.mark.parametrize("load", [0.15, None], ids=["constant", "diurnal"])
+def test_law_terms_and_noise_blocks_count_the_networks_work(db, tmp_path, load):
+    reqs = [dataclasses.replace(r, constant_load=load) for r in _requests()]
+    report, events = _traced(tmp_path, lambda: run_fleet(db, reqs, _config()))
+    run = _one(events, "repro.fleet.run")[3]
+    chunks = sum(len(s.report.samples) for s in report.sessions)
+    # every tenant draws its first block; a session's bulk chunks reuse
+    # the terms of its parameter choice
+    assert run["noise_blocks"] >= run["admissions"] == N
+    assert 0 < run["law_terms"] < chunks
+
+
 def test_the_strict_sharded_regime_carries_the_counters(db, tmp_path):
     cfg = EngineConfig(engine="sharded", n_shards=2, score_vs_single=False)
     _, events = _traced(tmp_path, lambda: run_fleet(db, _requests(8), cfg))
